@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core import CoCoAConfig, solve
 from repro.data import load, partition
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import Timer, maybe_plot, save
 
@@ -79,6 +80,7 @@ def run(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     run(quick=True)
 
 

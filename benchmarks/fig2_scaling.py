@@ -13,6 +13,7 @@ import numpy as np
 from repro.core import CoCoAConfig, solve
 from repro.core.baselines import run_minibatch_cd, run_minibatch_sgd
 from repro.data import load, partition
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import Timer, maybe_plot, save
 
@@ -96,6 +97,7 @@ def run(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     run(quick=True)
 
 

@@ -10,6 +10,7 @@ import numpy as np
 from repro.core import CoCoAConfig, solve
 from repro.core.sigma import sigma_prime_min
 from repro.data import load, partition
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import maybe_plot, save
 
@@ -55,6 +56,7 @@ def run(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     run(quick=True)
 
 

@@ -35,6 +35,7 @@ import numpy as np
 from repro.core.losses import get_loss
 from repro.core.solvers import local_sdca, local_sdca_sparse
 from repro.kernels.ops import local_sdca_block, sparse_local_sdca_block
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import fenced_call, fenced_time, save
 
@@ -735,6 +736,7 @@ def run(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     mode = ap.add_mutually_exclusive_group()
     mode.add_argument("--quick", action="store_true",

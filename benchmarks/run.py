@@ -19,8 +19,11 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import (fig1_convergence, fig2_scaling, fig3_sigma, kernel_bench,
                    table1_sigma)
+    enable_compile_cache()
 
     failures = 0
     for name, mod in [("table1", table1_sigma), ("fig1", fig1_convergence),

@@ -7,6 +7,7 @@ import numpy as np
 
 from repro.core.sigma import table1_ratio
 from repro.data import load, partition
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import save
 
@@ -35,6 +36,7 @@ def run(quick: bool = True):
 
 
 def main():
+    enable_compile_cache()
     run(quick=True)
 
 

@@ -59,6 +59,19 @@ from jax.sharding import PartitionSpec as P
 from .compress import merge_sets
 from .placement import WSpec
 
+
+def auto_axes(mesh):
+    """`mesh` with every axis typed `Auto`. `jax.make_mesh` types its axes
+    `Explicit` by default, which puts shardings into the array types; the
+    rounds place data only through shard_map specs and leave the global
+    ops around them (certificate scatters, host gathers) to the compiler,
+    so they need the untyped mesh."""
+    from jax.sharding import AxisType, Mesh
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
 REDUCE_KINDS = ("flat", "hier", "a2a")
 
 
@@ -157,7 +170,7 @@ class Topology:
             K *= mesh.shape[a]
         kind, g = parse_reduce(topology)
         return Topology(K=K, data_axes=daxes, model_axis=model_axis,
-                        mesh=mesh, reduce=kind, group=g)
+                        mesh=auto_axes(mesh), reduce=kind, group=g)
 
     # -- SPMD helpers --------------------------------------------------------
 

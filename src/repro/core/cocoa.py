@@ -373,12 +373,11 @@ def make_round_sharded(cfg: CoCoAConfig, mesh) -> Callable[..., CoCoAState]:
     partial-dot psum over the model axis inside each solver step. Both
     route through comm exactly like the vmap backend.
     """
-    from jax.experimental.shard_map import shard_map
-
     loss = get_loss(cfg.loss)
     reg = cfg.regularizer()
     topo = Topology.from_mesh(mesh, cfg.data_axis, cfg.model_axis,
                               topology=cfg.topology)
+    mesh = topo.mesh
     K = topo.K
     M = topo.M
     sharded_w = M > 1
@@ -447,8 +446,8 @@ def make_round_sharded(cfg: CoCoAConfig, mesh) -> Callable[..., CoCoAState]:
         out_specs = (topo.w_spec(), topo.row_spec(None), P(),
                      topo.row_spec(None), topo.row_spec(mspec)) \
             + ((P(),) if want_wire else ())
-        return shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _build_sparse():
         # replicated-w ELL path (global column ids); feature sharding
@@ -481,8 +480,8 @@ def make_round_sharded(cfg: CoCoAConfig, mesh) -> Callable[..., CoCoAState]:
                     topo.row_spec(None))           # alpha_bar
         out_specs = (P(), topo.row_spec(None), P(), topo.row_spec(None),
                      topo.row_spec(None)) + ((P(),) if want_wire else ())
-        return shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _build_sparse_fs():
         # feature-sharded ELL path: shard-local column ids against the
@@ -520,8 +519,8 @@ def make_round_sharded(cfg: CoCoAConfig, mesh) -> Callable[..., CoCoAState]:
         out_specs = (topo.w_spec(), topo.row_spec(None), P(),
                      topo.row_spec(None), topo.row_spec(mspec)) \
             + ((P(),) if want_wire else ())
-        return shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     built = {}
 
@@ -711,11 +710,17 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
         extra_args = lambda t: (budget_fn(t),)
     else:
         extra_args = lambda t: ()
-    # AOT-split trace+compile out of the per-round fenced timings (falls
-    # back to the jitted callable -- compile then lands in round 1's
-    # execute_s, still a correct total)
+    # AOT-split trace+compile out of the per-round fenced timings. The
+    # round decides where the state lives: a carried state (resumed, or
+    # rebuilt after a failure) enters from the host, so the executable is
+    # compiled for the placement its own output keeps. The data is
+    # loop-invariant, so it is placed once where the executable reads it
+    # instead of being transferred again every round
+    state = jax.tree.map(np.asarray, state)
     run_fn, pending_compile = aot_compile(round_fn, state, X, y, mask,
                                           *extra_args(0))
+    X, y, mask = jax.device_put((X, y, mask),
+                                run_fn.input_shardings[0][1:4])
     gap_run = None
     base_round = int(state.rounds)
     gap = float("inf")
@@ -724,19 +729,8 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     prev_floats = 0
     for t in range(rounds):
         with jax.profiler.StepTraceAnnotation("cocoa_round", step_num=t):
-            try:
-                state, dt = fenced_call(run_fn, state, X, y, mask,
-                                        *extra_args(t))
-            except Exception:
-                if run_fn is round_fn:
-                    raise
-                # the AOT executable pins input shardings; a carried
-                # state placed elsewhere (host rebuild after failure
-                # recovery / resharding) is rejected where jit would
-                # silently re-place it -- fall back to the jitted callable
-                run_fn = round_fn
-                state, dt = fenced_call(run_fn, state, X, y, mask,
-                                        *extra_args(t))
+            state, dt = fenced_call(run_fn, state, X, y, mask,
+                                    *extra_args(t))
         exec_acc += dt
         covered += 1
         tracer.tick()
@@ -768,13 +762,7 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
                 gap_run, dtc = aot_compile(gap_fn, *gargs)
                 pending_compile += dtc
             with jax.profiler.TraceAnnotation("cocoa_certificate"):
-                try:
-                    (pval, dval, g), cert_s = fenced_call(gap_run, *gargs)
-                except Exception:
-                    if gap_run is gap_fn:
-                        raise
-                    gap_run = gap_fn        # same sharding-pinning fallback
-                    (pval, dval, g), cert_s = fenced_call(gap_run, *gargs)
+                (pval, dval, g), cert_s = fenced_call(gap_run, *gargs)
             gap = float(g)
             totals = tracer.totals()
             rec = RoundRecord(

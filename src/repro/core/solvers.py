@@ -75,26 +75,6 @@ class SDCAResult(NamedTuple):
     steps: jnp.ndarray      # number of inner steps actually executed
 
 
-def _install_barrier_batching_rule():
-    """optimization_barrier has no vmap batching rule in this jax version,
-    which breaks every vmap-backend round (the K-worker simulation). The
-    barrier is semantically the identity, so batching it is just binding on
-    the batched operands and passing the batch dims through."""
-    from jax.interpreters import batching
-
-    prim = getattr(jax.lax, "optimization_barrier_p", None)
-    if prim is None or prim in batching.primitive_batchers:
-        return
-
-    def _rule(args, dims):
-        return prim.bind(*args), dims
-
-    batching.primitive_batchers[prim] = _rule
-
-
-_install_barrier_batching_rule()
-
-
 def local_sdca(X_k: jnp.ndarray, y_k: jnp.ndarray, alpha_k: jnp.ndarray,
                mask_k: jnp.ndarray, v: jnp.ndarray, rng: jax.Array,
                loss: Loss, lam: float, n, sigma_p: float, H: int,

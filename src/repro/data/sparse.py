@@ -432,6 +432,10 @@ def rmatvec(sh, coef: jnp.ndarray) -> jnp.ndarray:
     output is (d,) for `SparseShards`, the padded (M*d_local,) global
     vector for `FeatureShards` (per-shard local scatters, concatenated --
     padded coordinates receive nothing)."""
+    if isinstance(sh, FeatureShards) and sh.M == 1:
+        # one slice holds the global ids: take the replicated layout's
+        # scatter, so an M=1 certificate partitions (and rounds) the same
+        sh = SparseShards(sh.cols[:, 0], sh.vals[:, 0], sh.nnz[:, 0], d=sh.d)
     if isinstance(sh, FeatureShards):
         contrib = sh.vals * coef[:, None, :, None]        # (K, M, nk, r)
         per_m = jax.vmap(

@@ -23,10 +23,13 @@ Grid layout: grid = (n_passes, nk // block_rows); grid step (p, b) processes
 rows [b*B, (b+1)*B) sequentially with a fori_loop. dalpha/u land in the
 outputs only at the final grid step (no cross-step output aliasing hazards).
 
-VMEM budget (f32): B*d (X tile) + nk (dalpha) + 2*d (u, w) + 3*B floats.
-ops.py picks B so this stays under ~12 MiB. d and B should be multiples of
-128/8 on real TPUs; interpret=True (CPU CI) is shape-agnostic but we keep the
-aligned contract anyway.
+Mosaic lowers no dynamic slice of a *value*, so every per-step access is an
+indexed ref access: the row as `x_ref[pl.ds(i, 1), :]`, the per-row scalars
+(y, alpha, mask) from (1, B) SMEM tiles, and dalpha through the 128-lane
+layout below. On the chip B is a multiple of 128 (or the whole shard).
+
+VMEM budget (f32): 2*B*d (double-buffered X tile) + 3*nk (dalpha scratch
+and output) + 5*d (w, u, du) floats; ops.py pads d to a multiple of 128.
 
 Supported losses: the closed-form family ("hinge", "smooth_hinge*",
 "squared", "absolute"). "logistic" has no closed form -> use the pure-JAX
@@ -53,12 +56,54 @@ def _check_loss(loss: Loss):
             f"got {loss.name!r}; use the core.solvers JAX path instead")
 
 
+# ---------------------------------------------------------------------------
+# lane layout: a length-n f32 vector held as a (ceil(n/128), 128) VMEM ref.
+# Mosaic has no dynamic slice of a *value*, so a kernel that touches one
+# element at a runtime index loads the (1, 128) row holding it and picks or
+# replaces its lane with an iota mask -- O(1) vregs per access. The masked
+# lanes contribute exact zeros, so a read returns the element bit for bit,
+# and an update computes the new element as a scalar, the same float ops
+# (and, in interpret mode, the same XLA scalar fusion) as `x.at[i].set`.
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def lane_rows(n: int) -> int:
+    """Rows of the (rows, 128) lane layout of a length-n vector."""
+    return -(-n // LANES)
+
+
+def to_lanes(x: jnp.ndarray) -> jnp.ndarray:
+    """(n,) -> (lane_rows(n), 128), zero padded."""
+    return jnp.pad(x, (0, (-x.shape[0]) % LANES)).reshape(-1, LANES)
+
+
+def _lane_of(idx):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    return pl.ds(jax.lax.shift_right_logical(idx, 7), 1), \
+        lane == jnp.bitwise_and(idx, LANES - 1)
+
+
+def lane_get(ref, idx):
+    """ref[idx] of a lane-layout ref, as a scalar."""
+    row, hit = _lane_of(idx)
+    return jnp.sum(jnp.where(hit, ref[row, :], jnp.float32(0.0)))
+
+
+def lane_put(ref, idx, val):
+    """ref[idx] = val (a scalar) on a lane-layout ref."""
+    row, hit = _lane_of(idx)
+    ref[row, :] = jnp.where(hit, val, ref[row, :])
+
+
 def _sdca_kernel(scale_ref,                    # SMEM (1, 1): sigma'/(lambda n)
-                 x_ref, y_ref, a_ref, m_ref,   # VMEM tiles
+                 x_ref,                        # VMEM (block_rows, d) tile
+                 y_ref, a_ref, m_ref,          # SMEM (1, block_rows) tiles
                  w_ref,                        # VMEM (1, d)
-                 da_out, du_out,               # VMEM outputs (1, nk), (1, d)
-                 da_scr, u_scr,                # VMEM scratch (1, nk), (1, d)
-                 *, loss: Loss, block_rows: int, nk: int):
+                 da_out, du_out,               # VMEM (rows(nk), 128), (1, d)
+                 da_scr, u_scr,                # VMEM scratch, same shapes
+                 *, loss: Loss, block_rows: int):
     p = pl.program_id(0)
     b = pl.program_id(1)
     nb = pl.num_programs(1)
@@ -70,29 +115,19 @@ def _sdca_kernel(scale_ref,                    # SMEM (1, 1): sigma'/(lambda n)
         da_scr[...] = jnp.zeros_like(da_scr)
         u_scr[...] = w_ref[...]
 
-    x_blk = x_ref[...]                               # (block_rows, d)
-    y_blk = y_ref[...]                               # (1, block_rows)
-    m_blk = m_ref[...]
-    a_blk = a_ref[...]
     base = b * block_rows
 
-    def step(i, _):
-        x = jax.lax.dynamic_slice_in_dim(x_blk, i, 1, axis=0)      # (1, d)
+    def step(i, carry):
+        x = x_ref[pl.ds(i, 1), :]                                  # (1, d)
         u = u_scr[...]                                             # (1, d)
         z = jnp.sum(x * u)
-        sq = jnp.sum(x * x)
-        q = scale * sq
-        yi = jax.lax.dynamic_slice_in_dim(y_blk, i, 1, axis=1)[0, 0]
-        mi = jax.lax.dynamic_slice_in_dim(m_blk, i, 1, axis=1)[0, 0]
-        ai = jax.lax.dynamic_slice_in_dim(a_blk, i, 1, axis=1)[0, 0]
-        dai = jax.lax.dynamic_slice_in_dim(da_scr[...], base + i, 1,
-                                           axis=1)[0, 0]
-        abar = ai + dai
-        delta = loss.cd_update(abar, z, q, yi) * mi
-        da_scr[...] = jax.lax.dynamic_update_slice_in_dim(
-            da_scr[...], (dai + delta)[None, None], base + i, axis=1)
+        q = scale * jnp.sum(x * x)
+        dai = lane_get(da_scr, base + i)
+        delta = loss.cd_update(a_ref[0, i] + dai, z, q, y_ref[0, i]) \
+            * m_ref[0, i]
+        lane_put(da_scr, base + i, dai + delta)
         u_scr[...] = u + (scale * delta) * x
-        return 0
+        return carry
 
     jax.lax.fori_loop(0, block_rows, step, 0)
 
@@ -100,6 +135,24 @@ def _sdca_kernel(scale_ref,                    # SMEM (1, 1): sigma'/(lambda n)
     def _emit():
         da_out[...] = da_scr[...]
         du_out[...] = u_scr[...] - w_ref[...]
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """`interpret=None` runs the Pallas interpreter on the CPU backend only
+    (tests, laptops). Anywhere else the kernel is compiled; an explicit
+    value is never overridden."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
+def smem_rows(block_rows: int) -> pl.BlockSpec:
+    """SMEM BlockSpec of per-row scalars laid out (1, nk): one
+    (1, block_rows) block per grid row block. Mosaic wants the last block
+    dim a multiple of 128 or the whole array, so on the chip block_rows
+    is a multiple of 128 or the whole (padded) shard."""
+    return pl.BlockSpec((1, block_rows), lambda p, b: (0, b),
+                        memory_space=pltpu.SMEM)
 
 
 def local_sdca_pallas(X: jnp.ndarray, y: jnp.ndarray, alpha: jnp.ndarray,
@@ -117,34 +170,33 @@ def local_sdca_pallas(X: jnp.ndarray, y: jnp.ndarray, alpha: jnp.ndarray,
     nk, d = X.shape
     assert nk % block_rows == 0, (nk, block_rows)
     nb = nk // block_rows
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     f32 = jnp.float32
     kernel = functools.partial(_sdca_kernel, loss=loss,
-                               block_rows=block_rows, nk=nk)
+                               block_rows=block_rows)
     grid = (n_passes, nb)
+    rows = smem_rows(block_rows)
+    da_shape = (lane_rows(nk), LANES)
     da, du = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                 # scale
             pl.BlockSpec((block_rows, d), lambda p, b: (b, 0)),    # X
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # y
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # alpha
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # mask
+            rows, rows, rows,                                      # y, a, m
             pl.BlockSpec((1, d), lambda p, b: (0, 0)),             # w
         ],
         out_specs=[
-            pl.BlockSpec((1, nk), lambda p, b: (0, 0)),            # dalpha
+            pl.BlockSpec(da_shape, lambda p, b: (0, 0)),           # dalpha
             pl.BlockSpec((1, d), lambda p, b: (0, 0)),             # du
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, nk), f32),
+            jax.ShapeDtypeStruct(da_shape, f32),
             jax.ShapeDtypeStruct((1, d), f32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, nk), f32),
+            pltpu.VMEM(da_shape, f32),
             pltpu.VMEM((1, d), f32),
         ],
         interpret=interpret,
@@ -156,4 +208,4 @@ def local_sdca_pallas(X: jnp.ndarray, y: jnp.ndarray, alpha: jnp.ndarray,
         mask.astype(f32).reshape(1, nk),
         w.astype(f32).reshape(1, d),
     )
-    return da[0], du[0]
+    return da.reshape(-1)[:nk], du[0]

@@ -134,9 +134,9 @@ def sparse_local_sdca_block(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
 
     `shard` is a per-worker SparseShards (cols/vals (nk, r_max)). Same
     responsibilities as `local_sdca_block` -- fresh row permutation per
-    call, padding to the kernel's alignment contract (r_max and d to
-    multiples of 128 on real TPUs; padding entries are exact no-ops),
-    H -> whole passes.
+    call, padding nk to a multiple of block_rows (padded rows are masked
+    no-ops; the kernel lays u out in 128-lane rows itself), H -> whole
+    passes.
 
     Conjugate map: when `reg` carries a scalar soft-threshold form
     (`reg.prox_kappa`), the map is *fused into the kernel* -- applied to
@@ -174,15 +174,11 @@ def sparse_local_sdca_block(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
     # on static shapes only (d, r_max, backend) plus the reg family and
     # model-shard count (fused-prox and zx schedules tune differently;
     # zx wants smaller blocks, less intra-block staleness), since nnz is
-    # traced here. r_eff is the post-lane-padding slot count the
-    # kernel's unrolled walk actually runs, so the resolved slot_unroll
-    # divides it
-    lane = 128 if jax.default_backend() == "tpu" else 1
-    r_eff = r_max + (-r_max) % lane
+    # traced here
     M = int(jax.lax.psum(1, model_axis)) if model_axis is not None else 1
     cfg = resolve_sparse_config(d=d, r_max=r_max, block_rows=block_rows,
                                 slot_unroll=slot_unroll,
-                                buffer_depth=buffer_depth, r_eff=r_eff,
+                                buffer_depth=buffer_depth,
                                 reg_family=getattr(reg, "family", "other"),
                                 model_shards=M if use_zx else 1)
     # clamp the block to the (padded) shard *before* reporting: on small
@@ -210,12 +206,11 @@ def sparse_local_sdca_block(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
     ap = jnp.take(alpha_k, perm)
     mp = jnp.take(mask_k, perm)
 
-    cp = _pad_to(_pad_to(cp, br, 0), lane, 1)
-    vp = _pad_to(_pad_to(vp, br, 0), lane, 1)
+    cp = _pad_to(cp, br, 0)
+    vp = _pad_to(vp, br, 0)
     yp = _pad_to(yp, br, 0)
     ap = _pad_to(ap, br, 0)
     mp = _pad_to(mp, br, 0)
-    wp = _pad_to(w_in, lane, 0)
 
     scale = sigma_p / (reg.tau(lam) * jnp.asarray(n, jnp.float32))
     if use_zx:
@@ -230,11 +225,11 @@ def sparse_local_sdca_block(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
             sq = sqnorms
         sqp = _pad_to(jnp.take(sq, perm), br, 0)
         da_p, du_p = sparse_local_sdca_zx(
-            cp, vp, yp, ap, mp, wp, scale, sqp, loss=loss,
+            cp, vp, yp, ap, mp, w_in, scale, sqp, loss=loss,
             n_passes=n_passes, block_rows=br, slot_unroll=slot_unroll,
             prox_kappa=kappa, model_axis=model_axis, interpret=interpret)
     else:
-        da_p, du_p = sparse_local_sdca(cp, vp, yp, ap, mp, wp, scale,
+        da_p, du_p = sparse_local_sdca(cp, vp, yp, ap, mp, w_in, scale,
                                        loss=loss, n_passes=n_passes,
                                        block_rows=br,
                                        slot_unroll=slot_unroll,
@@ -256,11 +251,9 @@ def sparse_zx_plan(nk: int, d: int, H: int, *, r_max: int,
     `core.cocoa.solve` / the tracer can price the model-axis hop exactly:
     `exchanges` psums of `block_rows` floats per round per device."""
     backend = backend or jax.default_backend()
-    lane = 128 if backend == "tpu" else 1
-    r_eff = r_max + (-r_max) % lane
     cfg = resolve_sparse_config(d=d, r_max=r_max, block_rows=block_rows,
                                 slot_unroll=slot_unroll, buffer_depth=1,
-                                backend=backend, r_eff=r_eff,
+                                backend=backend,
                                 reg_family=reg_family,
                                 model_shards=model_shards)
     br = min(cfg["block_rows"], max(8, nk))

@@ -47,8 +47,9 @@ def sparse_local_sdca_ref(cols, vals, y, alpha, mask, w, scale, *,
     """Reference for kernels.sparse_sdca.sparse_local_sdca.
 
     Replays the kernel's exact op sequence -- scalar-indexed gather dot
-    (accumulated in row-slot order), scale * jnp.sum(v*v) row norm, and
-    sequential per-slot scatter-axpy -- so the comparison is bit-for-bit in
+    (accumulated in row-slot order), the row norms as one
+    jnp.sum(vals * vals, axis=1) ahead of the walk, and sequential
+    per-slot scatter-axpy -- so the comparison is bit-for-bit in
     interpret mode, including rows with duplicate columns. Padding slots
     (col 0, val 0.0) are exact no-ops, as in the kernel. `prox_kappa`
     mirrors the kernel's fused conjugate map: the same scalar
@@ -61,6 +62,7 @@ def sparse_local_sdca_ref(cols, vals, y, alpha, mask, w, scale, *,
     alpha = alpha.astype(jnp.float32)
     mask = mask.astype(jnp.float32)
     scale = jnp.asarray(scale, jnp.float32)
+    sq = jnp.sum(vals * vals, axis=1)
 
     def prox(uv):
         if prox_kappa is None:
@@ -82,7 +84,7 @@ def sparse_local_sdca_ref(cols, vals, y, alpha, mask, w, scale, *,
             return z + prox(uv) * vv
 
         z = jax.lax.fori_loop(0, r_max, gather_dot, jnp.float32(0.0))
-        q = scale * jnp.sum(vi * vi)
+        q = scale * sq[i]
         abar = alpha[i] + dalpha[i]
         delta = loss.cd_update(abar, z, q, y[i]) * mask[i]
         dalpha = dalpha.at[i].add(delta)

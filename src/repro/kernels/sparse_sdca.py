@@ -10,28 +10,32 @@ primal estimate u -- O(nnz) bytes, a 0.5/density reduction in HBM traffic
 
 Structure mirrors the dense kernel exactly:
 
-  * u (d floats) and dalpha (nk floats) live in VMEM scratch, persistent
-    across the sequential grid (p, b) = (pass, row block); outputs are
-    emitted at the final grid step only.
-  * the per-row gather/scatter walks the row's r_max slots with scalar
-    dynamic indexing on u; padding slots are (col 0, val 0.0), making them
-    exact arithmetic no-ops (gather adds u[0]*0, scatter adds 0 to u[0]) --
-    no per-row nnz bound is needed inside the kernel.
+  * u (d floats) and dalpha (nk floats) live in VMEM scratch in the
+    128-lane layout (`local_sdca.to_lanes`), persistent across the
+    sequential grid (p, b) = (pass, row block); outputs are emitted at the
+    final grid step only.
+  * the (B, r_max) cols/vals tiles and the per-row scalars (y, alpha, mask,
+    row sqnorm) arrive in SMEM, so every ELL slot is a scalar load. The
+    gather and the scatter touch u through one (1, 128) row per slot
+    (`lane_get` / `lane_put`), O(1) vregs per non-zero whatever d is.
+    Padding slots are (col 0, val 0.0), making them exact arithmetic
+    no-ops (gather adds u[0]*0, scatter adds 0 to u[0]) -- no per-row nnz
+    bound is needed inside the kernel.
   * the pure-jnp oracle `kernels.ref.sparse_local_sdca_ref` replays the
-    identical op sequence (same gather order, same reductions, same scatter
-    order), so kernel-vs-oracle equivalence is bit-for-bit in interpret
-    mode, not statistical.
+    identical op sequence (same gather order, row sqnorms computed the same
+    way ahead of the walk, same scatter order), so kernel-vs-oracle
+    equivalence is bit-for-bit in interpret mode, not statistical.
   * block-shuffled visit order and the closed-form loss family are shared
     with the dense path (the wrapper in ops.py applies the per-call row
     permutation; `_check_loss` rejects logistic).
 
 Pipelining (`buffer_depth`): the coordinate walk of block b only touches
-VMEM (u, dalpha, and the already-resident (B, r_max) cols/vals tiles), so
-the HBM fetch of block b+1 can hide entirely behind it. `buffer_depth=1`
-is the single-buffered kernel above, with the tiles delivered by the
-implicit Pallas pipeline. `buffer_depth>=2` switches to an explicitly
-multi-buffered kernel: cols/vals stay in HBM (`pltpu.ANY`), a
-(depth, B, r_max) VMEM scratch ring holds in-flight tiles, and
+on-chip memory (u, dalpha, and the already-resident (B, r_max) cols/vals
+tiles), so the HBM fetch of block b+1 can hide entirely behind it.
+`buffer_depth=1` is the single-buffered kernel above, with the tiles
+delivered by the implicit Pallas pipeline. `buffer_depth>=2` switches to
+an explicitly multi-buffered kernel: cols/vals stay in HBM (`pl.ANY`), a
+(depth*B, r_max) SMEM scratch ring holds in-flight tiles, and
 `pltpu.make_async_copy` DMAs prefetch block b+depth-1 while block b is
 walked (double buffering at depth 2 keeps one fetch in flight, quad at
 depth 4 keeps three -- deeper rings absorb burstier DMA latency). Both
@@ -50,13 +54,11 @@ legacy path emits today's jaxpr unchanged -- bit-for-bit with the PR-8
 kernel. The caller passes w = v (the scaled dual state) when fusing; u
 then lives in v-space and du = u - v is still scale * A_[k] dalpha.
 
-VMEM budget (f32): depth*B*r_max*8 bytes (cols+vals tile ring) + nk +
-2*d + 3*B floats -- at rcv1_sparse production shapes (d 47k, r_max ~128)
-well under 1 MiB even quad-buffered, vs ~24 MiB for the dense tile at
-the same d. On real TPUs r_max and d should be multiples of 128 (ops.py
-pads); interpret=True is shape-agnostic. `vmem_budget` prices every
-schedule (including the zx exchange buffers) and the entry points REJECT
-configs over the limit instead of leaning on the compiler clamp.
+Budget: SMEM holds the cols+vals tile ring (2 or depth slots of
+B*r_max*8 bytes) within `SMEM_BUDGET_KB`; VMEM holds the lane-layout u, w,
+du and dalpha vectors. `vmem_budget` prices every schedule (including the
+zx exchange buffers) and the entry points REJECT configs over either
+limit instead of leaning on the compiler clamp.
 
 Placement / M>1 (`sparse_local_sdca_zx`): `w` here is whatever shard the
 caller hands in -- gather-dot/scatter-axpy are coordinate-frame-agnostic,
@@ -85,7 +87,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.losses import Loss
-from .local_sdca import _check_loss
+from .local_sdca import (LANES, _check_loss, lane_get, lane_put, lane_rows,
+                         resolve_interpret, smem_rows, to_lanes)
 
 
 def _unrolled_fori(n: int, unroll: int, body, init):
@@ -119,67 +122,73 @@ def _prox(uv, prox_kappa):
     return jnp.sign(uv) * jnp.maximum(jnp.abs(uv) - kap, jnp.float32(0.0))
 
 
-def _block_walk(c_blk, v_blk, y_blk, a_blk, m_blk, base, da_scr, u_scr,
-                scale, *, loss: Loss, block_rows: int, r_max: int,
-                slot_unroll: int, prox_kappa: float | None = None):
-    """The sequential coordinate walk of one (block_rows, r_max) ELL tile
-    against the persistent u/dalpha scratch. Shared verbatim by the
-    single-buffered and the pipelined kernels -- identical tile values in,
-    bit-for-bit identical scratch updates out, whatever delivered the
-    tile (implicit Pallas pipeline or explicit DMA ring). With
-    `prox_kappa` set, each gathered u entry passes through the
-    soft-threshold conjugate map before the dot -- the per-step-exact
+def _gather_dot(u_ref, c_ref, v_ref, row, *, r_max: int, slot_unroll: int,
+                prox_kappa: float | None):
+    """z = sum_r prox(u[c_r]) * v_r over ELL row `row` of the SMEM refs
+    c_ref/v_ref, accumulated in slot order."""
+
+    def body(r, z):
+        uv = lane_get(u_ref, c_ref[row, r])
+        return z + _prox(uv, prox_kappa) * v_ref[row, r]
+
+    return _unrolled_fori(r_max, slot_unroll, body, jnp.float32(0.0))
+
+
+def _scatter_axpy(u_ref, c_ref, v_ref, row, coef, *, r_max: int,
+                  slot_unroll: int):
+    """u[c_r] += coef * v_r over ELL row `row`, one slot at a time (so
+    duplicate columns accumulate exactly as the sequential oracle)."""
+
+    def body(r, carry):
+        c = c_ref[row, r]
+        lane_put(u_ref, c, lane_get(u_ref, c) + coef * v_ref[row, r])
+        return carry
+
+    _unrolled_fori(r_max, slot_unroll, body, 0)
+
+
+def _block_walk(c_ref, v_ref, row0, y_ref, a_ref, m_ref, sq_ref, base,
+                da_scr, u_scr, scale, *, loss: Loss, block_rows: int,
+                r_max: int, slot_unroll: int,
+                prox_kappa: float | None = None):
+    """The sequential coordinate walk of one block of `block_rows` ELL rows
+    against the persistent lane-layout u/dalpha scratch. c_ref/v_ref are
+    SMEM refs holding the block's (col, val) rows from row `row0`;
+    y/alpha/mask/sqnorm come from (1, block_rows) SMEM tiles.
+    Shared verbatim by the single-buffered and the pipelined kernels --
+    identical tile values in, bit-for-bit identical scratch updates out,
+    whatever delivered the tile (implicit Pallas pipeline or explicit DMA
+    ring). With `prox_kappa` set, each gathered u entry passes through
+    the soft-threshold conjugate map before the dot -- the per-step-exact
     generalized subproblem; the scatter still updates raw (v-space) u."""
+    kw = dict(r_max=r_max, slot_unroll=slot_unroll)
 
-    def step(i, _):
-        ci = jax.lax.dynamic_index_in_dim(c_blk, i, axis=0, keepdims=False)
-        vi = jax.lax.dynamic_index_in_dim(v_blk, i, axis=0, keepdims=False)
-        u = u_scr[...][0]                                          # (d,)
-
-        def gather_dot(r, z):
-            c = jax.lax.dynamic_index_in_dim(ci, r, keepdims=False)
-            uv = jax.lax.dynamic_index_in_dim(u, c, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(vi, r, keepdims=False)
-            return z + _prox(uv, prox_kappa) * vv
-
-        z = _unrolled_fori(r_max, slot_unroll, gather_dot, jnp.float32(0.0))
-        q = scale * jnp.sum(vi * vi)
-        yi = jax.lax.dynamic_slice_in_dim(y_blk, i, 1, axis=1)[0, 0]
-        mi = jax.lax.dynamic_slice_in_dim(m_blk, i, 1, axis=1)[0, 0]
-        ai = jax.lax.dynamic_slice_in_dim(a_blk, i, 1, axis=1)[0, 0]
-        dai = jax.lax.dynamic_slice_in_dim(da_scr[...], base + i, 1,
-                                           axis=1)[0, 0]
-        abar = ai + dai
-        delta = loss.cd_update(abar, z, q, yi) * mi
-        da_scr[...] = jax.lax.dynamic_update_slice_in_dim(
-            da_scr[...], (dai + delta)[None, None], base + i, axis=1)
-        coef = scale * delta
-
-        def scatter_axpy(r, u):
-            c = jax.lax.dynamic_index_in_dim(ci, r, keepdims=False)
-            uv = jax.lax.dynamic_index_in_dim(u, c, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(vi, r, keepdims=False)
-            return jax.lax.dynamic_update_index_in_dim(
-                u, uv + coef * vv, c, axis=0)
-
-        u_scr[...] = _unrolled_fori(r_max, slot_unroll, scatter_axpy,
-                                    u)[None]
-        return 0
+    def step(i, carry):
+        row = row0 + i
+        z = _gather_dot(u_scr, c_ref, v_ref, row, prox_kappa=prox_kappa,
+                        **kw)
+        q = scale * sq_ref[0, i]
+        dai = lane_get(da_scr, base + i)
+        delta = loss.cd_update(a_ref[0, i] + dai, z, q, y_ref[0, i]) \
+            * m_ref[0, i]
+        lane_put(da_scr, base + i, dai + delta)
+        _scatter_axpy(u_scr, c_ref, v_ref, row, scale * delta, **kw)
+        return carry
 
     jax.lax.fori_loop(0, block_rows, step, 0)
 
 
 def _sparse_sdca_kernel(scale_ref,                     # SMEM (1, 1)
-                        c_ref, v_ref,                  # VMEM (B, r_max) tiles
-                        y_ref, a_ref, m_ref,           # VMEM (1, B) tiles
-                        w_ref,                         # VMEM (1, d)
-                        da_out, du_out,                # VMEM (1, nk), (1, d)
+                        c_ref, v_ref,                  # SMEM (B, r_max)
+                        y_ref, a_ref, m_ref, sq_ref,   # SMEM (1, B) tiles
+                        w_ref,                         # VMEM (rows(d), 128)
+                        da_out, du_out,                # VMEM lane layouts
                         da_scr, u_scr,                 # VMEM scratch
-                        *, loss: Loss, block_rows: int, nk: int, r_max: int,
+                        *, loss: Loss, block_rows: int, r_max: int,
                         slot_unroll: int = 1,
                         prox_kappa: float | None = None):
-    """Single-buffered (buffer_depth=1) kernel: cols/vals tiles arrive via
-    the implicit Pallas pipeline, one block resident at a time."""
+    """Single-buffered (buffer_depth=1) kernel: cols/vals tiles arrive in
+    SMEM via the implicit Pallas pipeline, one block resident at a time."""
     p = pl.program_id(0)
     b = pl.program_id(1)
     nb = pl.num_programs(1)
@@ -191,7 +200,7 @@ def _sparse_sdca_kernel(scale_ref,                     # SMEM (1, 1)
         da_scr[...] = jnp.zeros_like(da_scr)
         u_scr[...] = w_ref[...]
 
-    _block_walk(c_ref[...], v_ref[...], y_ref[...], a_ref[...], m_ref[...],
+    _block_walk(c_ref, v_ref, 0, y_ref, a_ref, m_ref, sq_ref,
                 b * block_rows, da_scr, u_scr, scale, loss=loss,
                 block_rows=block_rows, r_max=r_max, slot_unroll=slot_unroll,
                 prox_kappa=prox_kappa)
@@ -204,18 +213,18 @@ def _sparse_sdca_kernel(scale_ref,                     # SMEM (1, 1)
 
 def _sparse_sdca_pipelined_kernel(scale_ref,           # SMEM (1, 1)
                                   c_hbm, v_hbm,        # ANY (nk, r_max)
-                                  y_ref, a_ref, m_ref,  # VMEM (1, B) tiles
-                                  w_ref,               # VMEM (1, d)
-                                  da_out, du_out,      # VMEM (1, nk), (1, d)
+                                  y_ref, a_ref, m_ref, sq_ref,  # SMEM tiles
+                                  w_ref,               # VMEM (rows(d), 128)
+                                  da_out, du_out,      # VMEM lane layouts
                                   da_scr, u_scr,       # VMEM scratch
-                                  c_buf, v_buf,        # VMEM (depth, B, r_max)
+                                  c_buf, v_buf,        # SMEM (depth*B, r_max)
                                   c_sem, v_sem,        # DMA sems (depth,)
-                                  *, loss: Loss, block_rows: int, nk: int,
+                                  *, loss: Loss, block_rows: int,
                                   r_max: int, slot_unroll: int,
                                   buffer_depth: int,
                                   prox_kappa: float | None = None):
     """Explicitly multi-buffered kernel: cols/vals stay in HBM and a
-    depth-slot VMEM ring is fed by `make_async_copy` DMAs.
+    depth-slot SMEM ring is fed by `make_async_copy` DMAs.
 
     Chunk c of the flattened schedule (c = pass * nb + block) lands in
     ring slot c % depth. The warm-up step starts chunks 0..depth-2; every
@@ -230,13 +239,15 @@ def _sparse_sdca_pipelined_kernel(scale_ref,           # SMEM (1, 1)
     total = npass * nb
     g = p * nb + b                                     # flattened chunk id
     scale = scale_ref[0, 0]
+    B = block_rows
 
     def dma(chunk, slot):
         blk = jax.lax.rem(jnp.asarray(chunk, jnp.int32), jnp.int32(nb))
-        rows = pl.ds(blk * block_rows, block_rows)
-        return (pltpu.make_async_copy(c_hbm.at[rows, :], c_buf.at[slot],
+        src = pl.ds(blk * B, B)
+        dst = pl.ds(slot * B, B)
+        return (pltpu.make_async_copy(c_hbm.at[src], c_buf.at[dst],
                                       c_sem.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[rows, :], v_buf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[dst],
                                       v_sem.at[slot]))
 
     def start(chunk):
@@ -260,7 +271,7 @@ def _sparse_sdca_pipelined_kernel(scale_ref,           # SMEM (1, 1)
     for d_ in dma(g, slot):
         d_.wait()
 
-    _block_walk(c_buf[slot], v_buf[slot], y_ref[...], a_ref[...], m_ref[...],
+    _block_walk(c_buf, v_buf, slot * B, y_ref, a_ref, m_ref, sq_ref,
                 b * block_rows, da_scr, u_scr, scale, loss=loss,
                 block_rows=block_rows, r_max=r_max, slot_unroll=slot_unroll,
                 prox_kappa=prox_kappa)
@@ -269,6 +280,13 @@ def _sparse_sdca_pipelined_kernel(scale_ref,           # SMEM (1, 1)
     def _emit():
         da_out[...] = da_scr[...]
         du_out[...] = u_scr[...] - w_ref[...]
+
+
+def _compiler_params(vmem_limit_mb: int | None, interpret: bool) -> dict:
+    if vmem_limit_mb and not interpret:
+        return {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem_limit_mb) * 2**20)}
+    return {}
 
 
 def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
@@ -284,7 +302,8 @@ def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
     cols/vals: (nk, r_max) padded-ELL rows (padding = col 0 / val 0);
     y/alpha/mask: (nk,); w: (d,); scale: scalar sigma' / (lambda n).
     Returns (dalpha (nk,), du (d,)) with du = scale * A_[k] dalpha.
-    nk must be divisible by block_rows (ops.py pads).
+    nk must be divisible by block_rows (ops.py pads); on the chip
+    block_rows is also a multiple of 128 or the whole shard (SMEM tiling).
 
     `block_rows`, `slot_unroll`, and `buffer_depth` are the autotune
     knobs (`kernel_bench --autotune`): all three preserve the sequential
@@ -292,7 +311,9 @@ def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
     results. `buffer_depth=1` is the single-buffered kernel (tiles via
     the implicit Pallas pipeline); >=2 runs the explicitly multi-buffered
     kernel with a depth-slot DMA prefetch ring over the cols/vals tiles
-    (2 = double, 4 = quad buffering).
+    (2 = double, 4 = quad buffering). Its tiles stay in HBM, which Mosaic
+    cannot batch, so on the chip it runs unbatched (shard_map), not under
+    the vmap backend's `jax.vmap`.
 
     `prox_kappa` (static float, None = off) fuses the soft-threshold
     conjugate map into every gather -- pass w = v (scaled dual state)
@@ -300,8 +321,8 @@ def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
 
     `vmem_limit_mb` both raises Mosaic's VMEM ceiling on real TPUs and
     is the budget `vmem_budget` is enforced against (default 16 MiB) --
-    configs that blow it raise ValueError instead of relying on the
-    compiler clamp.
+    configs that blow it (or the fixed SMEM budget) raise ValueError
+    instead of relying on the compiler clamp.
     """
     _check_loss(loss)
     nk, r_max = cols.shape
@@ -310,8 +331,7 @@ def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
     assert vals.shape == (nk, r_max), (vals.shape, cols.shape)
     assert buffer_depth >= 1, buffer_depth
     nb = nk // block_rows
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     _enforce_vmem(vmem_budget(nk=nk, d=d, r_max=r_max,
                               block_rows=block_rows,
                               buffer_depth=buffer_depth,
@@ -319,87 +339,82 @@ def sparse_local_sdca(cols: jnp.ndarray, vals: jnp.ndarray, y: jnp.ndarray,
                   vmem_limit_mb, where="sparse_local_sdca")
 
     f32 = jnp.float32
-    grid = (n_passes, nb)
-    extra = {}
-    if vmem_limit_mb and not interpret:
-        params_cls = getattr(pltpu, "TPUCompilerParams", None)
-        if params_cls is not None:
-            extra["compiler_params"] = params_cls(
-                vmem_limit_bytes=int(vmem_limit_mb) * 2**20)
-
-    scratch = [
-        pltpu.VMEM((1, nk), f32),
-        pltpu.VMEM((1, d), f32),
-    ]
+    vals = vals.astype(f32)
+    sq = jnp.sum(vals * vals, axis=1)          # = kernels.ref's row norms
+    u_shape = (lane_rows(d), LANES)
+    da_shape = (lane_rows(nk), LANES)
+    scratch = [pltpu.VMEM(da_shape, f32), pltpu.VMEM(u_shape, f32)]
     if buffer_depth == 1:
         kernel = functools.partial(_sparse_sdca_kernel, loss=loss,
-                                   block_rows=block_rows, nk=nk,
-                                   r_max=r_max, slot_unroll=slot_unroll,
+                                   block_rows=block_rows, r_max=r_max,
+                                   slot_unroll=slot_unroll,
                                    prox_kappa=prox_kappa)
-        tile_specs = [
-            pl.BlockSpec((block_rows, r_max), lambda p, b: (b, 0)),  # cols
-            pl.BlockSpec((block_rows, r_max), lambda p, b: (b, 0)),  # vals
-        ]
+        tile_spec = pl.BlockSpec((block_rows, r_max), lambda p, b: (b, 0),
+                                 memory_space=pltpu.SMEM)
+        tile_specs = [tile_spec, tile_spec]                # cols, vals
     else:
         kernel = functools.partial(_sparse_sdca_pipelined_kernel, loss=loss,
-                                   block_rows=block_rows, nk=nk,
-                                   r_max=r_max, slot_unroll=slot_unroll,
+                                   block_rows=block_rows, r_max=r_max,
+                                   slot_unroll=slot_unroll,
                                    buffer_depth=buffer_depth,
                                    prox_kappa=prox_kappa)
-        # cols/vals stay in HBM; the kernel DMAs tiles into a VMEM ring
-        tile_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),                  # cols
-            pl.BlockSpec(memory_space=pltpu.ANY),                  # vals
-        ]
+        # cols/vals stay in HBM; the kernel DMAs tiles into an SMEM ring.
+        # A DMA moves whole 128-lane rows, so the slots are padded to a
+        # lane multiple; the walk still visits only the r_max real slots
+        tile_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        r_pad = (-r_max) % LANES
+        cols = jnp.pad(cols, ((0, 0), (0, r_pad)))
+        vals = jnp.pad(vals, ((0, 0), (0, r_pad)))
         scratch += [
-            pltpu.VMEM((buffer_depth, block_rows, r_max), jnp.int32),
-            pltpu.VMEM((buffer_depth, block_rows, r_max), f32),
+            pltpu.SMEM((buffer_depth * block_rows, r_max + r_pad), jnp.int32),
+            pltpu.SMEM((buffer_depth * block_rows, r_max + r_pad), f32),
             pltpu.SemaphoreType.DMA((buffer_depth,)),
             pltpu.SemaphoreType.DMA((buffer_depth,)),
         ]
+    rows = smem_rows(block_rows)
+    lanes = pl.BlockSpec(u_shape, lambda p, b: (0, 0))
     da, du = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_passes, nb),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                 # scale
             *tile_specs,
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # y
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # alpha
-            pl.BlockSpec((1, block_rows), lambda p, b: (0, b)),    # mask
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),             # w
+            rows, rows, rows, rows,                        # y, a, m, sq
+            lanes,                                                 # w
         ],
         out_specs=[
-            pl.BlockSpec((1, nk), lambda p, b: (0, 0)),            # dalpha
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),             # du
+            pl.BlockSpec(da_shape, lambda p, b: (0, 0)),           # dalpha
+            lanes,                                                 # du
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, nk), f32),
-            jax.ShapeDtypeStruct((1, d), f32),
+            jax.ShapeDtypeStruct(da_shape, f32),
+            jax.ShapeDtypeStruct(u_shape, f32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-        **extra,
+        **_compiler_params(vmem_limit_mb, interpret),
     )(
         jnp.asarray(scale, f32).reshape(1, 1),
         cols.astype(jnp.int32),
-        vals.astype(f32),
+        vals,
         y.astype(f32).reshape(1, nk),
         alpha.astype(f32).reshape(1, nk),
         mask.astype(f32).reshape(1, nk),
-        w.astype(f32).reshape(1, d),
+        sq.reshape(1, nk),
+        to_lanes(w.astype(f32)),
     )
-    return da[0], du[0]
+    return da.reshape(-1)[:nk], du.reshape(-1)[:d]
 
 
 def _sparse_sdca_zx_kernel(scale_ref,                  # SMEM (1, 1)
-                           c_ref, v_ref,               # VMEM (B, r_max) walk
-                           cn_ref, vn_ref,             # VMEM (B, r_max) next
-                           z_ref,                      # VMEM (1, B) exchanged
-                           y_ref, a_ref, m_ref,        # VMEM (1, B)
-                           sq_ref,                     # VMEM (1, B) global q
-                           da_ref,                     # VMEM (1, B) dalpha in
-                           u_ref,                      # VMEM (1, d) u in
-                           u_out, da_out, zn_out,      # (1,d), (1,B), (1,B)
+                           c_ref, v_ref,               # SMEM (B, r_max) walk
+                           cn_ref, vn_ref,             # SMEM (B, r_max) next
+                           z_ref,                      # SMEM (1, B) exchanged
+                           y_ref, a_ref, m_ref,        # SMEM (1, B)
+                           sq_ref,                     # SMEM (1, B) global q
+                           da_ref,                     # SMEM (1, B) dalpha in
+                           u_ref,                      # VMEM (rows(d), 128)
+                           u_out, da_out, zn_out,      # VMEM, SMEM, SMEM
                            *, loss: Loss, block_rows: int, r_max: int,
                            slot_unroll: int, prox_kappa: float | None):
     """One block of the z-exchange (M>1) schedule.
@@ -414,61 +429,26 @@ def _sparse_sdca_zx_kernel(scale_ref,                  # SMEM (1, 1)
     model shards, so the emitted dalpha is replicated by construction;
     only the u scatter touches shard-local columns."""
     scale = scale_ref[0, 0]
+    kw = dict(r_max=r_max, slot_unroll=slot_unroll)
     u_out[...] = u_ref[...]
-    da_out[...] = da_ref[...]
 
-    def step(i, _):
-        ci = jax.lax.dynamic_index_in_dim(c_ref[...], i, axis=0,
-                                          keepdims=False)
-        vi = jax.lax.dynamic_index_in_dim(v_ref[...], i, axis=0,
-                                          keepdims=False)
-        z = jax.lax.dynamic_slice_in_dim(z_ref[...], i, 1, axis=1)[0, 0]
-        q = scale * jax.lax.dynamic_slice_in_dim(sq_ref[...], i, 1,
-                                                 axis=1)[0, 0]
-        yi = jax.lax.dynamic_slice_in_dim(y_ref[...], i, 1, axis=1)[0, 0]
-        mi = jax.lax.dynamic_slice_in_dim(m_ref[...], i, 1, axis=1)[0, 0]
-        ai = jax.lax.dynamic_slice_in_dim(a_ref[...], i, 1, axis=1)[0, 0]
-        dai = jax.lax.dynamic_slice_in_dim(da_out[...], i, 1, axis=1)[0, 0]
-        abar = ai + dai
-        delta = loss.cd_update(abar, z, q, yi) * mi
-        da_out[...] = jax.lax.dynamic_update_slice_in_dim(
-            da_out[...], (dai + delta)[None, None], i, axis=1)
-        coef = scale * delta
-        u = u_out[...][0]
-
-        def scatter_axpy(r, u):
-            c = jax.lax.dynamic_index_in_dim(ci, r, keepdims=False)
-            uv = jax.lax.dynamic_index_in_dim(u, c, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(vi, r, keepdims=False)
-            return jax.lax.dynamic_update_index_in_dim(
-                u, uv + coef * vv, c, axis=0)
-
-        u_out[...] = _unrolled_fori(r_max, slot_unroll, scatter_axpy,
-                                    u)[None]
-        return 0
+    def step(i, carry):
+        q = scale * sq_ref[0, i]
+        dai = da_ref[0, i]
+        delta = loss.cd_update(a_ref[0, i] + dai, z_ref[0, i], q,
+                               y_ref[0, i]) * m_ref[0, i]
+        da_out[0, i] = dai + delta
+        _scatter_axpy(u_out, c_ref, v_ref, i, scale * delta, **kw)
+        return carry
 
     jax.lax.fori_loop(0, block_rows, step, 0)
 
     # local partial gather-dots for the next block at the updated u --
     # same ascending slot order as _block_walk's gather, prox fused
-    u = u_out[...][0]
-
-    def next_dot(i, _):
-        ci = jax.lax.dynamic_index_in_dim(cn_ref[...], i, axis=0,
-                                          keepdims=False)
-        vi = jax.lax.dynamic_index_in_dim(vn_ref[...], i, axis=0,
-                                          keepdims=False)
-
-        def gather_dot(r, z):
-            c = jax.lax.dynamic_index_in_dim(ci, r, keepdims=False)
-            uv = jax.lax.dynamic_index_in_dim(u, c, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(vi, r, keepdims=False)
-            return z + _prox(uv, prox_kappa) * vv
-
-        z = _unrolled_fori(r_max, slot_unroll, gather_dot, jnp.float32(0.0))
-        zn_out[...] = jax.lax.dynamic_update_slice_in_dim(
-            zn_out[...], z[None, None], i, axis=1)
-        return 0
+    def next_dot(i, carry):
+        zn_out[0, i] = _gather_dot(u_out, cn_ref, vn_ref, i,
+                                   prox_kappa=prox_kappa, **kw)
+        return carry
 
     jax.lax.fori_loop(0, block_rows, next_dot, 0)
 
@@ -508,8 +488,7 @@ def sparse_local_sdca_zx(cols: jnp.ndarray, vals: jnp.ndarray,
     assert vals.shape == (nk, r_max), (vals.shape, cols.shape)
     assert sqnorms.shape == (nk,), sqnorms.shape
     nb = nk // B
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     _enforce_vmem(vmem_budget(nk=nk, d=d, r_max=r_max, block_rows=B,
                               buffer_depth=1,
                               prox_fused=prox_kappa is not None, zx=True),
@@ -525,33 +504,22 @@ def sparse_local_sdca_zx(cols: jnp.ndarray, vals: jnp.ndarray,
     sq = sqnorms.astype(f32)
     scale = jnp.asarray(scale, f32)
 
-    extra = {}
-    if vmem_limit_mb and not interpret:
-        params_cls = getattr(pltpu, "TPUCompilerParams", None)
-        if params_cls is not None:
-            extra["compiler_params"] = params_cls(
-                vmem_limit_bytes=int(vmem_limit_mb) * 2**20)
-
     kernel = functools.partial(_sparse_sdca_zx_kernel, loss=loss,
                                block_rows=B, r_max=r_max,
                                slot_unroll=slot_unroll,
                                prox_kappa=prox_kappa)
-    grid = (1,)
-    tile = pl.BlockSpec((B, r_max), lambda g: (0, 0))
-    vec = pl.BlockSpec((1, B), lambda g: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    u_shape = (lane_rows(d), LANES)
     zx_call = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  tile, tile, tile, tile, vec, vec, vec, vec, vec, vec,
-                  pl.BlockSpec((1, d), lambda g: (0, 0))],
-        out_specs=[pl.BlockSpec((1, d), lambda g: (0, 0)),
-                   vec, vec],
-        out_shape=[jax.ShapeDtypeStruct((1, d), f32),
+        in_specs=[smem] * 11 + [vmem],
+        out_specs=[vmem, smem, smem],
+        out_shape=[jax.ShapeDtypeStruct(u_shape, f32),
                    jax.ShapeDtypeStruct((1, B), f32),
                    jax.ShapeDtypeStruct((1, B), f32)],
         interpret=interpret,
-        **extra,
+        **_compiler_params(vmem_limit_mb, interpret),
     )
 
     def partial_dots(u, c_blk, v_blk):
@@ -573,20 +541,19 @@ def sparse_local_sdca_zx(cols: jnp.ndarray, vals: jnp.ndarray,
         nxt = (g + 1) % nb
         sl = lambda arr, at: jax.lax.dynamic_slice_in_dim(  # noqa: E731
             arr, at * B, B, axis=0)
+        row = lambda arr: sl(arr, blk).reshape(1, B)       # noqa: E731
         u2, da2, zn = zx_call(
             scale.reshape(1, 1), sl(cols, blk), sl(vals, blk),
-            sl(cols, nxt), sl(vals, nxt), z_ex.reshape(1, B),
-            sl(y, blk).reshape(1, B), sl(alpha, blk).reshape(1, B),
-            sl(mask, blk).reshape(1, B), sl(sq, blk).reshape(1, B),
-            sl(dal, blk).reshape(1, B), u.reshape(1, d))
+            sl(cols, nxt), sl(vals, nxt), z_ex.reshape(1, B), row(y),
+            row(alpha), row(mask), row(sq), row(dal), u)
         dal = jax.lax.dynamic_update_slice_in_dim(dal, da2[0], blk * B,
                                                   axis=0)
-        return (u2[0], dal, exchange(zn[0])), None
+        return (u2, dal, exchange(zn[0])), None
 
     (u, dalpha, _), _ = jax.lax.scan(
-        body, (w, jnp.zeros(nk, f32), z0),
+        body, (to_lanes(w), jnp.zeros(nk, f32), z0),
         jnp.arange(n_passes * nb, dtype=jnp.int32))
-    return dalpha, u - w
+    return dalpha, u.reshape(-1)[:d] - w
 
 
 def zx_exchanges(nk: int, block_rows: int, n_passes: int = 1) -> int:
@@ -595,41 +562,54 @@ def zx_exchanges(nk: int, block_rows: int, n_passes: int = 1) -> int:
     return n_passes * (nk // block_rows) + 1
 
 
+# v5e has 1 MiB of SMEM per core; the ELL tiles may take three quarters,
+# the rest is left to the compiler's own scalars
+SMEM_BUDGET_KB = 768
+
+
 def vmem_budget(nk: int, d: int, r_max: int, block_rows: int = 128,
                 buffer_depth: int = 1, prox_fused: bool = False,
                 model_shards: int = 1, zx: bool | None = None) -> dict:
-    """Static VMEM working set of one grid step (f32/int32 = 4 bytes).
+    """Static on-chip working set of one launch (f32/int32 = 4 bytes).
 
-    At depth >= 2 the cols/vals tile is a depth-slot ring (the DMA
-    prefetch buffers); u/dalpha are depth-independent. The fused prox
-    is applied in-register on each gathered scalar -- zero extra VMEM
+    SMEM holds the cols/vals tiles and the per-row scalars
+    (y/alpha/mask/sqnorm). Depth 1 gets its tiles from the implicit Pallas
+    pipeline, which double-buffers them; depth >= 2 keeps a depth-slot DMA
+    ring whose rows are padded to 128 lanes. VMEM holds the lane-layout
+    vectors: w in and du out (each double-buffered by the pipeline) and
+    the u scratch, plus dalpha's scratch and output. The fused prox is
+    applied in-register on each gathered scalar -- zero extra memory
     (prox_kb stays 0; the flag is recorded so callers can see which
     schedule was priced). `zx` prices the z-exchange kernel instead
     (defaults on when model_shards > 1): two (B, r_max) cols+vals tile
     pairs (walk + next block), u resident twice (in + out), eight
-    B-sized lane vectors (z/y/alpha/mask/sqnorms/dalpha-in/out/zn) --
-    and no full-nk dalpha, which lives outside the kernel in the scan
-    carry. `zx_exchange_kb` is the psum'd wire buffer (block_rows
-    floats)."""
+    B-sized scalar rows (z/y/alpha/mask/sqnorms/dalpha-in/out/zn) -- and
+    no full-nk dalpha, which lives outside the kernel in the scan carry.
+    `zx_exchange_kb` is the psum'd wire buffer (block_rows floats)."""
     f = 4
     B = block_rows
     if zx is None:
         zx = model_shards > 1
     depth = max(1, buffer_depth)
     dense_tile = B * d * f
+    u = -(-d // 128) * 128 * f
     if zx:
         tile = 2 * B * r_max * 2 * f           # walk + next (cols + vals)
-        u = d * f
         dalpha = B * f                         # in-kernel slice only
-        total = tile + 2 * u + 8 * B * f
+        smem = tile + 8 * B * f
+        total = 2 * u
     else:
-        tile = depth * B * r_max * 2 * f       # cols + vals ring
-        u = d * f
-        dalpha = nk * f
-        total = tile + 2 * u + dalpha + 3 * B * f
-    return dict(ell_tile_kb=tile / 1024, u_kb=u / 1024,
-                dalpha_kb=dalpha / 1024, total_mb=total / 2**20,
-                fits_16mb=total < 16 * 2**20,
+        slots, r_w = (2, r_max) if depth == 1 else \
+            (depth, r_max + (-r_max) % 128)
+        tile = slots * B * r_w * 2 * f         # cols + vals ring
+        dalpha = -(-nk // 128) * 128 * f
+        smem = tile + 2 * 4 * B * f
+        total = 5 * u + 3 * dalpha
+    return dict(ell_tile_kb=tile / 1024, smem_kb=smem / 1024,
+                u_kb=u / 1024, dalpha_kb=dalpha / 1024,
+                total_mb=total / 2**20,
+                fits_16mb=(total < 16 * 2**20
+                           and smem <= SMEM_BUDGET_KB * 1024),
                 dense_tile_mb=dense_tile / 2**20,
                 buffer_depth=depth, prox_fused=bool(prox_fused),
                 prox_kb=0.0, zx=bool(zx),
@@ -640,14 +620,20 @@ def vmem_budget(nk: int, d: int, r_max: int, block_rows: int = 128,
 def _enforce_vmem(budget: dict, vmem_limit_mb: int | None, *,
                   where: str) -> None:
     """Reject launch configs whose priced working set exceeds the VMEM
-    limit (default 16 MiB) -- a loud ValueError at dispatch beats the
-    compiler silently clamping/spilling (or interpret mode hiding it)."""
+    limit (default 16 MiB) or the SMEM budget -- a loud ValueError at
+    dispatch beats the compiler silently clamping/spilling (or interpret
+    mode hiding it)."""
     limit = float(vmem_limit_mb) if vmem_limit_mb else 16.0
     if budget["total_mb"] > limit:
         raise ValueError(
             f"{where}: priced VMEM working set {budget['total_mb']:.2f} "
             f"MiB exceeds the {limit:.0f} MiB limit "
-            f"(block_rows x r_max tile ring {budget['ell_tile_kb']:.0f} "
-            f"KiB, u {budget['u_kb']:.0f} KiB, dalpha "
-            f"{budget['dalpha_kb']:.0f} KiB, zx={budget['zx']}); shrink "
-            f"block_rows/buffer_depth or raise vmem_limit_mb")
+            f"(u {budget['u_kb']:.0f} KiB, dalpha "
+            f"{budget['dalpha_kb']:.0f} KiB, zx={budget['zx']}); raise "
+            f"vmem_limit_mb")
+    if budget["smem_kb"] > SMEM_BUDGET_KB:
+        raise ValueError(
+            f"{where}: priced SMEM working set {budget['smem_kb']:.0f} KiB "
+            f"exceeds the {SMEM_BUDGET_KB} KiB budget (block_rows x r_max "
+            f"tile ring {budget['ell_tile_kb']:.0f} KiB, zx={budget['zx']}); "
+            f"shrink block_rows/buffer_depth")

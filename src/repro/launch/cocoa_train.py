@@ -60,11 +60,13 @@ from repro.core.regularizers import get_regularizer
 from repro.data import DATASETS, load, partition
 from repro.data.sparse import (FeatureShards, SparseShards, partition_sparse,
                                shard_features)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Aggregator, Dashboard, EventBus, JsonlSink, ProfilerSink
 from repro.runtime import elastic, failures, straggler
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="covtype_like")
     ap.add_argument("--loss", default="hinge")
@@ -393,13 +395,9 @@ def main():
                 mesh = (jax.make_mesh((K, M), ("data", "model")) if M > 1
                         else jax.make_mesh((K,), ("data",)))
             st = init_state(wspec.d_padded, K, nk_dim)
+            # solve re-places the carried leaves for the new mesh
             state = st._replace(alpha=new["alpha"], w=state.w,
                                 rounds=state.rounds)
-            if mesh is not None:
-                # the carried leaves are committed to the old mesh's
-                # devices; pull them to host so the new mesh re-places them
-                state = jax.tree.map(lambda x: jnp.asarray(np.asarray(x)),
-                                     state)
             el_round = -1
 
     if mgr:

@@ -401,7 +401,6 @@ def moe_forward(params, x, cfg, dff):
 
 def _moe_forward_shardmap(params, x, cfg, dff):
     """Explicit-EP MoE: shard_map over the full mesh; see MOE_CTX docs."""
-    from jax.experimental.shard_map import shard_map
 
     mesh = MOE_CTX["mesh"]
     tp = MOE_CTX["tp"]
@@ -495,12 +494,12 @@ def _moe_forward_shardmap(params, x, cfg, dff):
 
     P_ = P
     w_spec = P_(tp, fsdp, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(w_spec, w_spec, w_spec, P_(fsdp, None),
                   P_(dp, None, None)),
         out_specs=(P_(dp, None, None), P_()),
-        check_rep=False,
+        check_vma=False,
     )(params["wi"], params["wg"], params["wo"], params["router"], x)
     if "shared" in params:   # shared expert: plain TP outside the shard_map
         out = out + mlp_forward(params["shared"],

@@ -140,15 +140,11 @@ def fenced_time(fn, *args, iters: int = 3, warmup: int = 1, **kwargs):
 def aot_compile(jit_fn, *args):
     """Split trace+compile out of execution: returns `(runnable,
     compile_s)` where `runnable(*args)` is the AOT-compiled executable
-    and `compile_s` the one-time lowering+compile wall-clock. Falls back
-    to `(jit_fn, 0.0)` when the function cannot be lowered (non-jitted
-    callables, exotic input trees) -- the first fenced call then simply
-    includes compile, which is still a correct total."""
+    and `compile_s` the one-time lowering+compile wall-clock. A lowering
+    or compile error (a kernel the device's compiler refuses) is raised
+    here, on the first attempt."""
     t0 = time.perf_counter()
-    try:
-        compiled = jit_fn.lower(*args).compile()
-    except Exception:
-        return jit_fn, 0.0
+    compiled = jit_fn.lower(*args).compile()
     return compiled, time.perf_counter() - t0
 
 

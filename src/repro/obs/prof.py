@@ -6,9 +6,9 @@ accounting); this module is the computation half -- the compute-side twin
 of `comm.CommTracer.per_hop()`:
 
   * `HardwareSpec` -- the peak constants a roofline is stated against
-    (FLOP/s, HBM bytes/s, interconnect bytes/s), pluggable instead of
-    hard-coded TPU numbers, with CPU-host defaults so the quick CI path
-    produces sane achieved fractions.
+    (FLOP/s, HBM bytes/s, interconnect bytes/s), keyed by the device's
+    `device_kind` with their published source, plus CPU-host defaults so
+    the CPU tests produce sane achieved fractions.
   * `KernelProfile` -- frozen, schema-versioned (like `RoundRecord`): one
     profiled computation, carrying the *measured* fenced wall-clock next
     to the *analytic* cost extracted from its lowered post-optimization
@@ -72,16 +72,17 @@ class HardwareSpec:
 
 
 # one x86 host core with AVX FMA is O(100) GFLOP/s f32 and O(20) GB/s to
-# DRAM -- honest single-process defaults, so CPU CI runs land at plausible
-# (sub-1) achieved fractions instead of the 1e-6 a TPU denominator gives
+# DRAM -- honest single-process defaults for the CPU tests, so their
+# achieved fractions land at plausible (sub-1) values. Never a device peak.
 CPU_HOST = HardwareSpec("cpu_host", peak_flops=1e11, hbm_bw=2e10,
                         ici_bw=1e10)
-TPU_V5E = HardwareSpec("tpu_v5e", peak_flops=197e12, hbm_bw=819e9,
-                       ici_bw=50e9)
-TPU_V4 = HardwareSpec("tpu_v4", peak_flops=275e12, hbm_bw=1228e9,
-                      ici_bw=100e9)
+# per chip, named by jax's `device_kind`. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# inter-chip interconnect (= 200e9 bytes/s)
+TPU_V5E = HardwareSpec("TPU v5 lite", peak_flops=197e12, hbm_bw=819e9,
+                       ici_bw=200e9)
 
-HARDWARE = {h.name: h for h in (CPU_HOST, TPU_V5E, TPU_V4)}
+HARDWARE = {h.name: h for h in (CPU_HOST, TPU_V5E)}
 
 
 def get_hardware(name: str) -> HardwareSpec:
@@ -92,9 +93,14 @@ def get_hardware(name: str) -> HardwareSpec:
 
 
 def default_hardware() -> HardwareSpec:
-    """TPU peaks when running on TPU, CPU-host peaks otherwise."""
+    """The peaks of the device JAX runs on, looked up by `device_kind`
+    (CPU_HOST on the CPU backend). A device missing from the table is an
+    error, never a stand-in."""
     import jax
-    return TPU_V5E if jax.default_backend() == "tpu" else CPU_HOST
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_HOST
+    return get_hardware(dev.device_kind)
 
 
 # ----------------------------------------------------------------------------

@@ -105,7 +105,6 @@ def make_round_sharded(loss_fn: Callable, cfg: LocalDPConfig, mesh,
                        data_axis: str = "data"):
     """Production path: shard_map over the data axis; one psum of the
     (optionally compressed) delta per round."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     prox = cfg.prox0 * cfg.resolved_sigma()
@@ -133,8 +132,8 @@ def make_round_sharded(loss_fn: Callable, cfg: LocalDPConfig, mesh,
     def round_fn(params, batches):
         bspec = jax.tree.map(lambda _: P(data_axis), batches)
         pspec = jax.tree.map(lambda _: P(), params)
-        return shard_map(per_shard, mesh=mesh,
-                         in_specs=(pspec, bspec),
-                         out_specs=pspec, check_rep=False)(params, batches)
+        return jax.shard_map(per_shard, mesh=mesh,
+                             in_specs=(pspec, bspec), out_specs=pspec,
+                             check_vma=False)(params, batches)
 
     return round_fn

@@ -102,6 +102,26 @@ def test_kernel_rejects_logistic():
                           interpret=True)
 
 
+def test_interpret_false_is_not_overridden_off_tpu():
+    """The interpreter is chosen only for interpret=None on the CPU
+    backend; an explicit interpret=False is compiled as asked, so off a
+    TPU the launch fails instead of silently interpreting."""
+    from repro.kernels.local_sdca import resolve_interpret
+    from repro.kernels.sparse_sdca import sparse_local_sdca
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    X, y, a, m, w = _mk(64, 128)
+    with pytest.raises(ValueError, match="interpret"):
+        local_sdca_pallas(X, y, a, m, w, 1.0, loss=get_loss("hinge"),
+                          block_rows=64, interpret=False)
+    cols = jnp.zeros((64, 8), jnp.int32)
+    with pytest.raises(ValueError, match="interpret"):
+        sparse_local_sdca(cols, jnp.zeros((64, 8)), y, a, m, w, 1.0,
+                          loss=get_loss("hinge"), block_rows=64,
+                          interpret=False)
+
+
 # ----------------------------------------------------------------------------
 # fused selective-scan kernel (mamba) -- the memory-roofline fix for
 # falcon-mamba train cells (EXPERIMENTS.md section Roofline)

@@ -339,13 +339,13 @@ def test_budget_split_encode_masks_dead_slots():
     the model axis (subprocess with forced host devices)."""
     out = _run("""
         import jax, numpy as np, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.comm import compress
+        from repro.comm.topology import auto_axes
 
         M, d_loc, k = 2, 16, 5
         comp = compress.TopK(k).with_shards(M, "model")
-        mesh = jax.make_mesh((1, M), ("data", "model"))
+        mesh = auto_axes(jax.make_mesh((1, M), ("data", "model")))
         x = jnp.asarray(np.random.default_rng(0)
                         .standard_normal((M, d_loc)).astype(np.float32))
         ef = jnp.zeros((M, d_loc))
@@ -355,11 +355,11 @@ def test_budget_split_encode_masks_dead_slots():
             msg, res = comp.encode(xm[0], em[0], rm[0])
             return msg.idx[None], msg.val[None], res[None]
 
-        idx, val, res = shard_map(
+        idx, val, res = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(P("model"), P("model"), P("model")),
             out_specs=(P("model"), P("model"), P("model")),
-            check_rep=False)(x, ef, rngs)
+            check_vma=False)(x, ef, rngs)
         slots = -(-k // M)
         assert idx.shape == (M, slots), idx.shape
         live = [int(np.sum(np.asarray(idx[m]) < d_loc)) for m in range(M)]

@@ -386,13 +386,19 @@ def test_sparse_vmem_budget_production_shape():
     vm = vmem_budget(nk=16384, d=47236, r_max=128)    # rcv1-scale shard
     assert vm["fits_16mb"]
     assert vm["dense_tile_mb"] > 10 * vm["total_mb"]  # the point of the kernel
-    # multi-buffering scales only the cols/vals tile term, linearly in
-    # depth; the rcv1-scale shard still fits double-buffered
+    # multi-buffering scales only the cols/vals tile ring, which lives in
+    # SMEM: depth 1 is double-buffered by the implicit pipeline, so depth
+    # 2 prices the same and depth 4 twice; the VMEM side (u, dalpha) does
+    # not move, and the rcv1-scale shard still fits quad-buffered
     vm2 = vmem_budget(nk=16384, d=47236, r_max=128, buffer_depth=2)
+    vm4 = vmem_budget(nk=16384, d=47236, r_max=128, buffer_depth=4)
     assert vm2["buffer_depth"] == 2 and vm2["fits_16mb"]
-    assert vm2["ell_tile_kb"] == pytest.approx(2 * vm["ell_tile_kb"])
-    assert vm2["total_mb"] - vm["total_mb"] \
-        == pytest.approx(vm["ell_tile_kb"] / 1024)
+    assert vm4["fits_16mb"]
+    assert vm2["ell_tile_kb"] == pytest.approx(vm["ell_tile_kb"])
+    assert vm4["ell_tile_kb"] == pytest.approx(2 * vm["ell_tile_kb"])
+    assert vm4["smem_kb"] - vm["smem_kb"] \
+        == pytest.approx(vm["ell_tile_kb"])
+    assert vm4["total_mb"] == vm["total_mb"]
 
 
 # ----------------------------------------------------------------------------
@@ -635,21 +641,26 @@ def test_sparse_vmem_rejection():
     launched: the priced working set names the limit it exceeds, and an
     explicit vmem_limit_mb raises the ceiling."""
     loss = get_loss("hinge")
-    cols = jnp.zeros((1024, 1024), jnp.int32)
-    vals = jnp.zeros((1024, 1024))
+    cols = jnp.zeros((1024, 64), jnp.int32)
+    vals = jnp.zeros((1024, 64))
     one = jnp.ones(1024)
     w_big = jnp.zeros(2_000_000)
     with pytest.raises(ValueError, match="exceeds"):
         sparse_local_sdca(cols, vals, one, jnp.zeros(1024), one, w_big,
-                          1.0, loss=loss, block_rows=1024, buffer_depth=4,
+                          1.0, loss=loss, block_rows=128, buffer_depth=4,
                           interpret=True)
     # same config under a raised explicit limit prices fine
     from repro.kernels.sparse_sdca import _enforce_vmem
-    b = vmem_budget(nk=1024, d=2_000_000, r_max=1024, block_rows=1024,
+    b = vmem_budget(nk=1024, d=2_000_000, r_max=64, block_rows=128,
                     buffer_depth=4)
     _enforce_vmem(b, 64, where="test")                  # no raise
     with pytest.raises(ValueError, match="test"):
         _enforce_vmem(b, 16, where="test")
+    # a tile ring over the SMEM budget is rejected whatever the VMEM limit
+    big = vmem_budget(nk=1024, d=1024, r_max=1024, block_rows=1024,
+                      buffer_depth=4)
+    with pytest.raises(ValueError, match="SMEM"):
+        _enforce_vmem(big, 64, where="test")
 
 
 # ----------------------------------------------------------------------------
