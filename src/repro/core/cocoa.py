@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -61,7 +62,7 @@ from repro.comm.topology import Topology
 from repro.data import sparse as sparse_data
 from repro.data.sparse import FeatureShards, SparseShards
 from repro.obs.events import Aggregator, EventBus
-from repro.obs.metrics import RoundRecord, aot_compile, fenced_call
+from repro.obs.metrics import RoundRecord, aot_stages, fenced_call, span
 
 from . import duality
 from .accel import AccelSpec, init_accel_state, parse_accel, wrap_round
@@ -607,186 +608,217 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     fed each round with (steps_done, fenced round seconds) -- its EMA
     rates and the budgets land in the records.
 
+    The call runs under host spans (`obs.metrics.span`), which land in
+    any active `jax.profiler` trace: `cocoa_solve` around it all, and
+    inside it `cocoa_lower` / `cocoa_compile` (tagged `what=round` or
+    `what=certificate`), `cocoa_place` (the state's host copy and the
+    data's placement), `cocoa_round` per round, `cocoa_certificate`,
+    `cocoa_record` (building and emitting a record) and `cocoa_on_round`
+    around the caller's hook. A record's `host_s` is the call's time in
+    none of the lowering, compile, round, certificate or hook spans.
+
     The state's w width follows the placement: WSpec.d_padded (= M *
     ceil(d/M)) under feature sharding, d otherwise; dense X is zero-padded
     along its feature axis to match (padded coordinates carry no data and
     stay exactly zero).
     """
-    if isinstance(X, FeatureShards):
-        K, _, nk = X.cols.shape[:3]
-        d = X.d
-        dtype = X.vals.dtype
-    elif isinstance(X, SparseShards):
-        K, nk = X.cols.shape[:2]
-        d = X.d
-        dtype = X.vals.dtype
-    else:
-        K, nk, d = X.shape
-        dtype = X.dtype
-    loss = get_loss(cfg.loss)
-    reg = cfg.regularizer()
-
-    if cfg.backend == "shard_map":
-        assert mesh is not None, "shard_map backend needs a mesh"
-        topo = Topology.from_mesh(mesh, cfg.data_axis, cfg.model_axis,
-                                  topology=cfg.topology)
-        wspec = topo.wspec(d)
-        if isinstance(X, FeatureShards) and X.M != wspec.M:
-            raise ValueError(f"FeatureShards sliced for M={X.M} but the "
-                             f"mesh's model axis carries M={wspec.M}")
-        if wspec.sharded and not isinstance(X, (FeatureShards,
-                                                SparseShards)):
-            X = jnp.pad(X, ((0, 0), (0, 0), (0, wspec.d_padded - d)))
-        base_round_fn = make_round_sharded(cfg, mesh)
-    else:
-        topo = Topology.simulated(K, topology=cfg.topology)
-        wspec = topo.wspec(d)
+    with span("cocoa_solve"):
+        # host_s bookkeeping: the clock since the last record (since entry,
+        # for the first), less what the lowering, compile, round,
+        # certificate and hook spans below held
+        mark, held = time.perf_counter(), 0.0
         if isinstance(X, FeatureShards):
-            raise ValueError("FeatureShards need the shard_map backend on "
-                             "a 2-D mesh; the vmap reference runs on "
-                             "SparseShards with the global column ids")
-        base_round_fn = make_round_vmap(cfg, K)
-    # outer momentum lifts the round operator BEFORE jit, so extrapolate +
-    # solve + exchange compile as one computation; accel="none" returns
-    # the base round itself (bit-for-bit the plain path, not a wrapper)
-    aspec = cfg.accel_spec()
-    round_fn = jax.jit(wrap_round(base_round_fn, aspec))
-    if state is None:
-        state = init_state(wspec.d_padded, K, nk, seed, dtype)
-    if cfg.gather and topo.reduce == "hier" and state.wire is None:
-        # the round emits a measured-wire scalar under hier gather; give
-        # it a stable leaf up front so round 1 and round 2 share one jit
-        # signature (None -> array would retrace the whole round)
-        state = state._replace(wire=jnp.zeros((), jnp.int32))
-    # same stable-leaf contract for the momentum pair (v_prev = w makes
-    # the first accelerated round exactly a plain round); a checkpoint
-    # from a plain run restores leafless and momentum simply starts here
-    state = init_accel_state(state, aspec)
+            K, _, nk = X.cols.shape[:3]
+            d = X.d
+            dtype = X.vals.dtype
+        elif isinstance(X, SparseShards):
+            K, nk = X.cols.shape[:2]
+            d = X.d
+            dtype = X.vals.dtype
+        else:
+            K, nk, d = X.shape
+            dtype = X.dtype
+        loss = get_loss(cfg.loss)
+        reg = cfg.regularizer()
 
-    compressed = cfg.compress not in (None, "none", "")
-    # lossy messages AND extrapolated exchange points both make the
-    # carried v drift from v(alpha) -- either way the certificate must
-    # price the iterate the algorithm actually holds
-    drifted = compressed or aspec.enabled
-    if drifted:
-        # certify the primal point w = grad g*(tau v) at the state's
-        # carried (NON-extrapolated) v (still >= D by weak duality)
-        gap_fn = jax.jit(_scoped("cocoa/certificate", functools.partial(
-            duality.gap_at_v, loss=loss, lam=cfg.lam, reg=reg)))
-    else:
-        gap_fn = jax.jit(_scoped("cocoa/certificate", functools.partial(
-            duality.gap_decomposed, loss=loss, lam=cfg.lam, reg=reg)))
+        if cfg.backend == "shard_map":
+            assert mesh is not None, "shard_map backend needs a mesh"
+            topo = Topology.from_mesh(mesh, cfg.data_axis, cfg.model_axis,
+                                      topology=cfg.topology)
+            wspec = topo.wspec(d)
+            if isinstance(X, FeatureShards) and X.M != wspec.M:
+                raise ValueError(f"FeatureShards sliced for M={X.M} but the "
+                                 f"mesh's model axis carries M={wspec.M}")
+            if wspec.sharded and not isinstance(X, (FeatureShards,
+                                                    SparseShards)):
+                X = jnp.pad(X, ((0, 0), (0, 0), (0, wspec.d_padded - d)))
+            base_round_fn = make_round_sharded(cfg, mesh)
+        else:
+            topo = Topology.simulated(K, topology=cfg.topology)
+            wspec = topo.wspec(d)
+            if isinstance(X, FeatureShards):
+                raise ValueError("FeatureShards need the shard_map backend on "
+                                 "a 2-D mesh; the vmap reference runs on "
+                                 "SparseShards with the global column ids")
+            base_round_fn = make_round_vmap(cfg, K)
+        # outer momentum lifts the round operator BEFORE jit, so extrapolate +
+        # solve + exchange compile as one computation; accel="none" returns
+        # the base round itself (bit-for-bit the plain path, not a wrapper)
+        aspec = cfg.accel_spec()
+        round_fn = jax.jit(wrap_round(base_round_fn, aspec))
+        if state is None:
+            state = init_state(wspec.d_padded, K, nk, seed, dtype)
+        if cfg.gather and topo.reduce == "hier" and state.wire is None:
+            # the round emits a measured-wire scalar under hier gather; give
+            # it a stable leaf up front so round 1 and round 2 share one jit
+            # signature (None -> array would retrace the whole round)
+            state = state._replace(wire=jnp.zeros((), jnp.int32))
+        # same stable-leaf contract for the momentum pair (v_prev = w makes
+        # the first accelerated round exactly a plain round); a checkpoint
+        # from a plain run restores leafless and momentum simply starts here
+        state = init_accel_state(state, aspec)
 
-    # per-round communication accounting: the topology's reduce plan priced
-    # by the compressor's wire model (per hop under hier/a2a, the sparse
-    # (idx, val) sets under compressed gather); feature sharding divides
-    # the dense message length to d/M per hop -- Fig-2 claims stay honest
-    # under tensor sharding, compression, and multi-hop topologies. The
-    # model-axis tax of the sharded solver is carried as its own hop so
-    # per-axis tables add up: one scalar psum per coordinate step on the
-    # jnp path, or the kernel path's block-batched z-exchange (priced from
-    # the same resolve/clamp arithmetic the dispatch launches with).
-    zx_plan = None
-    if wspec.sharded and isinstance(X, FeatureShards) and \
-            sparse_counterpart(cfg.solver) == "sdca_sparse_kernel":
-        from repro.kernels.ops import sparse_zx_plan
-        zx_plan = sparse_zx_plan(nk, wspec.d_local, cfg.H,
-                                 r_max=int(X.cols.shape[-1]),
-                                 reg_family=getattr(reg, "family", "other"),
-                                 model_shards=wspec.M)
-    tracer = comm.CommTracer.for_run(
-        K=K, d_local=topo.d_local(d),
-        compressor=cfg.compressor(M=wspec.M),
-        topo=topo, gather=cfg.gather,
-        extra_hops=comm.model_hops(wspec, K, cfg.H, zx_plan=zx_plan)
-        # momentum's priced (empty) wire plan -- asserts zero extra floats
-        + comm.accel_hops(cfg.accel))
+        compressed = cfg.compress not in (None, "none", "")
+        # lossy messages AND extrapolated exchange points both make the
+        # carried v drift from v(alpha) -- either way the certificate must
+        # price the iterate the algorithm actually holds
+        drifted = compressed or aspec.enabled
+        if drifted:
+            # certify the primal point w = grad g*(tau v) at the state's
+            # carried (NON-extrapolated) v (still >= D by weak duality)
+            gap_fn = jax.jit(_scoped("cocoa/certificate", functools.partial(
+                duality.gap_at_v, loss=loss, lam=cfg.lam, reg=reg)))
+        else:
+            gap_fn = jax.jit(_scoped("cocoa/certificate", functools.partial(
+                duality.gap_decomposed, loss=loss, lam=cfg.lam, reg=reg)))
 
-    # --- the instrumented round loop -----------------------------------
-    # `agg` collects the emitted records; the returned history is its
-    # view, so history and any external bus sink describe the same bytes.
-    agg = Aggregator()
-    if budget_fn is not None and cfg.backend != "shard_map":
-        extra_args = lambda t: (budget_fn(t),)
-    else:
-        extra_args = lambda t: ()
-    # AOT-split trace+compile out of the per-round fenced timings. The
-    # round decides where the state lives: a carried state (resumed, or
-    # rebuilt after a failure) enters from the host, so the executable is
-    # compiled for the placement its own output keeps. The data is
-    # loop-invariant, so it is placed once where the executable reads it
-    # instead of being transferred again every round
-    state = jax.tree.map(np.asarray, state)
-    run_fn, pending_compile = aot_compile(round_fn, state, X, y, mask,
-                                          *extra_args(0))
-    X, y, mask = jax.device_put((X, y, mask),
-                                run_fn.input_shardings[0][1:4])
-    gap_run = None
-    base_round = int(state.rounds)
-    gap = float("inf")
-    exec_acc = 0.0
-    covered = 0
-    prev_floats = 0
-    for t in range(rounds):
-        with jax.profiler.StepTraceAnnotation("cocoa_round", step_num=t):
-            state, dt = fenced_call(run_fn, state, X, y, mask,
-                                    *extra_args(t))
-        exec_acc += dt
-        covered += 1
-        tracer.tick()
-        if state.wire is not None:
-            # hier compressed gather: replace the inter hop's analytic
-            # upper bound with the measured post-dedup volume
-            tracer.observe("inter_gather", state.wire)
-        budgets = (np.asarray(budget_fn(t))
-                   if budget_fn is not None else None)
-        if throughput is not None:
-            # bulk-synchronous round: every worker shares the fenced
-            # round wall-clock; steps actually run are the budgets (or H)
-            throughput.observe_round(
-                budgets if budgets is not None else float(cfg.H), dt)
-        if (t + 1) % gap_every == 0 or t == rounds - 1:
-            alpha_eval = state.alpha
-            if cfg.average_iterates:
-                alpha_eval = state.alpha_bar / jnp.maximum(state.rounds, 1)
-            if aspec.enabled and loss.project is not None:
-                # extrapolated coordinates can sit a whisker outside the
-                # conjugate's domain (where l* = +inf would read the dual
-                # as -inf); certify a feasible dual point instead -- still
-                # a true bound by weak duality, and the projection
-                # residual vanishes as the iterates converge
-                alpha_eval = loss.project(alpha_eval, y)
-            gargs = ((state.w, alpha_eval, X, y, mask) if drifted
-                     else (alpha_eval, X, y, mask))
-            if gap_run is None:
-                gap_run, dtc = aot_compile(gap_fn, *gargs)
-                pending_compile += dtc
-            with jax.profiler.TraceAnnotation("cocoa_certificate"):
-                (pval, dval, g), cert_s = fenced_call(gap_run, *gargs)
-            gap = float(g)
-            totals = tracer.totals()
-            rec = RoundRecord(
-                round=t + 1,
-                round_global=base_round + t + 1,
-                rounds_in_record=covered,
-                gap=gap, primal=float(pval), dual=float(dval),
-                compile_s=pending_compile, execute_s=exec_acc,
-                certificate_s=cert_s,
-                wire_floats=totals["comm_floats"] - prev_floats,
-                wire_bytes=4 * (totals["comm_floats"] - prev_floats),
-                hops=tuple(tracer.per_hop()),
-                comm=totals,
-                budgets=(tuple(int(b) for b in budgets)
-                         if budgets is not None else None),
-                throughput=(tuple(float(r) for r in throughput.rate)
-                            if throughput is not None else None))
-            prev_floats = totals["comm_floats"]
-            pending_compile, exec_acc, covered = 0.0, 0.0, 0
-            agg.emit(rec)
-            if obs is not None:
-                obs.emit(rec)
-            if on_round is not None:
-                on_round(t + 1, state, gap)
-            if gap <= eps_gap:
-                break
-    return SolveResult(state, agg.history())
+        # per-round communication accounting: the topology's reduce plan priced
+        # by the compressor's wire model (per hop under hier/a2a, the sparse
+        # (idx, val) sets under compressed gather); feature sharding divides
+        # the dense message length to d/M per hop -- Fig-2 claims stay honest
+        # under tensor sharding, compression, and multi-hop topologies. The
+        # model-axis tax of the sharded solver is carried as its own hop so
+        # per-axis tables add up: one scalar psum per coordinate step on the
+        # jnp path, or the kernel path's block-batched z-exchange (priced from
+        # the same resolve/clamp arithmetic the dispatch launches with).
+        zx_plan = None
+        if wspec.sharded and isinstance(X, FeatureShards) and \
+                sparse_counterpart(cfg.solver) == "sdca_sparse_kernel":
+            from repro.kernels.ops import sparse_zx_plan
+            zx_plan = sparse_zx_plan(
+                nk, wspec.d_local, cfg.H, r_max=int(X.cols.shape[-1]),
+                reg_family=getattr(reg, "family", "other"),
+                model_shards=wspec.M)
+        tracer = comm.CommTracer.for_run(
+            K=K, d_local=topo.d_local(d),
+            compressor=cfg.compressor(M=wspec.M),
+            topo=topo, gather=cfg.gather,
+            extra_hops=comm.model_hops(wspec, K, cfg.H, zx_plan=zx_plan)
+            # momentum's priced (empty) wire plan -- asserts zero extra floats
+            + comm.accel_hops(cfg.accel))
+
+        # --- the instrumented round loop -----------------------------------
+        # `agg` collects the emitted records; the returned history is its
+        # view, so history and any external bus sink describe the same bytes.
+        agg = Aggregator()
+        if budget_fn is not None and cfg.backend != "shard_map":
+            extra_args = lambda t: (budget_fn(t),)
+        else:
+            extra_args = lambda t: ()
+        # AOT-split trace+compile out of the per-round fenced timings. The
+        # round decides where the state lives: a carried state (resumed, or
+        # rebuilt after a failure) enters from the host, so the executable is
+        # compiled for the placement its own output keeps. The data is
+        # loop-invariant, so it is placed once where the executable reads it
+        # instead of being transferred again every round
+        with span("cocoa_place", what="state"):
+            state = jax.tree.map(np.asarray, state)
+        run_fn, pending_lower, load_s = aot_stages(
+            round_fn, state, X, y, mask, *extra_args(0), what="round")
+        pending_compile = pending_lower + load_s
+        held += pending_compile
+        with span("cocoa_place", what="data"):
+            X, y, mask = jax.device_put((X, y, mask),
+                                        run_fn.input_shardings[0][1:4])
+        gap_run = None
+        base_round = int(state.rounds)
+        gap = float("inf")
+        exec_acc = 0.0
+        covered = 0
+        prev_floats = 0
+        for t in range(rounds):
+            with span("cocoa_round", step=t) as round_span:
+                state, dt = fenced_call(run_fn, state, X, y, mask,
+                                        *extra_args(t))
+            held += round_span.seconds
+            exec_acc += dt
+            covered += 1
+            tracer.tick()
+            if state.wire is not None:
+                # hier compressed gather: replace the inter hop's analytic
+                # upper bound with the measured post-dedup volume
+                tracer.observe("inter_gather", state.wire)
+            budgets = (np.asarray(budget_fn(t))
+                       if budget_fn is not None else None)
+            if throughput is not None:
+                # bulk-synchronous round: every worker shares the fenced
+                # round wall-clock; steps actually run are the budgets (or H)
+                throughput.observe_round(
+                    budgets if budgets is not None else float(cfg.H), dt)
+            if (t + 1) % gap_every == 0 or t == rounds - 1:
+                alpha_eval = state.alpha
+                if cfg.average_iterates:
+                    alpha_eval = state.alpha_bar / jnp.maximum(state.rounds, 1)
+                if aspec.enabled and loss.project is not None:
+                    # extrapolated coordinates can sit a whisker outside the
+                    # conjugate's domain (where l* = +inf would read the dual
+                    # as -inf); certify a feasible dual point instead -- still
+                    # a true bound by weak duality, and the projection
+                    # residual vanishes as the iterates converge
+                    alpha_eval = loss.project(alpha_eval, y)
+                gargs = ((state.w, alpha_eval, X, y, mask) if drifted
+                         else (alpha_eval, X, y, mask))
+                if gap_run is None:
+                    gap_run, lower_s, load_s = aot_stages(
+                        gap_fn, *gargs, what="certificate")
+                    pending_lower += lower_s
+                    pending_compile += lower_s + load_s
+                    held += lower_s + load_s
+                with span("cocoa_certificate") as cert_span:
+                    (pval, dval, g), cert_s = fenced_call(gap_run, *gargs)
+                held += cert_span.seconds
+                with span("cocoa_record"):
+                    now = time.perf_counter()
+                    host_s, mark, held = now - mark - held, now, 0.0
+                    gap = float(g)
+                    totals = tracer.totals()
+                    rec = RoundRecord(
+                        round=t + 1,
+                        round_global=base_round + t + 1,
+                        rounds_in_record=covered,
+                        gap=gap, primal=float(pval), dual=float(dval),
+                        compile_s=pending_compile, lower_s=pending_lower,
+                        execute_s=exec_acc, certificate_s=cert_s,
+                        host_s=host_s,
+                        wire_floats=totals["comm_floats"] - prev_floats,
+                        wire_bytes=4 * (totals["comm_floats"] - prev_floats),
+                        hops=tuple(tracer.per_hop()),
+                        comm=totals,
+                        budgets=(tuple(int(b) for b in budgets)
+                                 if budgets is not None else None),
+                        throughput=(tuple(float(r) for r in throughput.rate)
+                                    if throughput is not None else None))
+                    prev_floats = totals["comm_floats"]
+                    pending_compile = pending_lower = 0.0
+                    exec_acc, covered = 0.0, 0
+                    agg.emit(rec)
+                    if obs is not None:
+                        obs.emit(rec)
+                if on_round is not None:
+                    # the caller's time, not the solver's
+                    with span("cocoa_on_round") as hook_span:
+                        on_round(t + 1, state, gap)
+                    held += hook_span.seconds
+                if gap <= eps_gap:
+                    break
+        return SolveResult(state, agg.history())

@@ -106,12 +106,17 @@ def duality_gap(alpha: jnp.ndarray, X, y: jnp.ndarray,
 def gap_decomposed(alpha, X, y, mask, loss, lam, reg: Regularizer = L2):
     """Returns (P, D, gap) sharing the one v(alpha) rmatvec -- the
     dominant cost of a certificate -- between the primal and dual sides
-    (rather than rebuilding it inside `dual`)."""
+    (rather than rebuilding it inside `dual`). The three passes run under
+    the named scopes `rmatvec`, `primal` and `dual`, so a profile times
+    each apart."""
     n = effective_n(mask)
-    v = v_of_alpha(X, alpha, lam, n, reg)
-    w = reg.conj_grad(v, lam)
-    p = primal(w, X, y, mask, loss, lam, reg)
-    d = dual_at_v(v, alpha, y, mask, loss, lam, reg)
+    with jax.named_scope("rmatvec"):
+        v = v_of_alpha(X, alpha, lam, n, reg)
+    with jax.named_scope("primal"):
+        w = reg.conj_grad(v, lam)
+        p = primal(w, X, y, mask, loss, lam, reg)
+    with jax.named_scope("dual"):
+        d = dual_at_v(v, alpha, y, mask, loss, lam, reg)
     return p, d, p - d
 
 
@@ -128,9 +133,14 @@ def gap_at_w(w, alpha, X, y, mask, loss, lam, reg: Regularizer = L2):
     Feature-sharded runs pass the padded (M*d_local,) w with
     `FeatureShards` data: predictions assemble via one model-axis
     reduction inside `_Atw`, and the padded coordinates (always zero, no
-    column maps to them) contribute nothing to g(w)."""
-    p = primal(w, X, y, mask, loss, lam, reg)
-    d = dual(alpha, X, y, mask, loss, lam, reg)
+    column maps to them) contribute nothing to g(w). Named scopes as in
+    `gap_decomposed`."""
+    with jax.named_scope("primal"):
+        p = primal(w, X, y, mask, loss, lam, reg)
+    with jax.named_scope("rmatvec"):
+        v = v_of_alpha(X, alpha, lam, effective_n(mask), reg)
+    with jax.named_scope("dual"):
+        d = dual_at_v(v, alpha, y, mask, loss, lam, reg)
     return p, d, p - d
 
 

@@ -14,10 +14,13 @@ order. The bundled sinks:
     internal `Aggregator`).
   * `ProfilerSink` -- starts a `jax.profiler` trace on creation and
     stops it on `close()`; together with the `jax.named_scope`
-    annotations in `core.cocoa` (`cocoa/local_solve`, `cocoa/exchange`,
-    `cocoa/certificate`) and the host-side `StepTraceAnnotation` per
-    round, the TPU trace viewer shows solver / exchange / certificate
-    regions per round.
+    annotations in `core.cocoa` and `core.duality` (`cocoa/local_solve`,
+    `cocoa/exchange`, `cocoa/certificate` with its `rmatvec`, `primal`
+    and `dual` passes) and the host spans of `solve` (`cocoa_solve`,
+    `cocoa_lower`, `cocoa_compile`, `cocoa_place`, `cocoa_round`,
+    `cocoa_certificate`, `cocoa_record`, `cocoa_on_round`), the TPU
+    trace viewer shows solver / exchange / certificate regions per round
+    and where the host spent the rest of the call.
 
 A sink is anything with `emit(record)` (plain callables work too --
 `bus.subscribe(print)` is valid); `close()` is optional. Sinks must not
@@ -210,10 +213,14 @@ class Aggregator:
 class ProfilerSink:
     """`jax.profiler` trace over the run: starts on construction (so
     compile is captured), stops on `close()`. Inspect with the TPU trace
-    viewer / TensorBoard; the `cocoa/*` named scopes and per-round
-    `StepTraceAnnotation`s emitted by `core.cocoa` mark solver, exchange,
-    and certificate regions. Never fails the run: profiler errors print
-    a note and disable the sink."""
+    viewer / TensorBoard; the `cocoa/*` named scopes mark solver,
+    exchange and certificate regions (the certificate's `rmatvec`,
+    `primal` and `dual` passes apart), and the host spans of `solve`
+    (`cocoa_solve` enclosing `cocoa_lower`, `cocoa_compile`,
+    `cocoa_place`, the per-round step span `cocoa_round`,
+    `cocoa_certificate`, `cocoa_record` and `cocoa_on_round`) say where
+    the host time went. Never fails the run: profiler errors print a
+    note and disable the sink."""
 
     def __init__(self, logdir: Union[str, pathlib.Path]):
         self.logdir = str(logdir)

@@ -1,26 +1,31 @@
-"""Metric primitives, fenced wall-clock timing, and the per-round record.
+"""Metric primitives, spans and fenced wall-clock timing, and the
+per-round record.
 
 Everything this reproduction claims is a statement about *gap vs. rounds
 vs. communication vs. time*; the first three were always measured (the
 duality certificate and `comm.CommTracer`) and this module adds the
 fourth. Three layers:
 
-  * `Counter` / `Gauge` / `Histogram` -- minimal in-process metric
-    primitives (no external deps; `Histogram` keeps raw samples so
-    percentiles are exact at round-count scale).
-  * fenced timing -- `fenced_call` runs a JAX computation and blocks
-    until every output buffer is ready before reading the clock, so the
-    number is device wall-clock, not dispatch latency. `aot_compile`
-    splits the one-time trace+compile cost out of the steady-state
-    per-round time (`jit(...).lower(args).compile()`); the trainer and
-    the benchmarks share these two helpers, so their numbers are
+  * `Histogram` -- an in-process sample distribution (no external deps;
+    keeps raw samples so percentiles are exact at round-count scale).
+  * spans and fenced timing -- `span` is a host span that lands in any
+    active `jax.profiler` trace (a `TraceAnnotation`, on the same clock
+    as the device planes) and keeps its own `perf_counter` seconds.
+    `fenced_call` runs a JAX computation and blocks until every output
+    buffer is ready before reading the clock, so the number is device
+    wall-clock, not dispatch latency. `aot_compile` splits the one-time
+    cost out of the steady-state per-round time under two spans,
+    `cocoa_lower` (`jit(...).trace(args).lower()`) and `cocoa_compile`
+    (`.compile()`: a backend compile or a persistent-cache load); the
+    trainer and the benchmarks share these helpers, so their numbers are
     comparable by construction.
   * `RoundRecord` -- the frozen, schema-versioned record `core.cocoa.
     solve` emits once per certified round: the certificate triple, the
-    wall-clock split (compile / execute / certificate), the wire plan
-    (`hops` is `CommTracer.per_hop()` verbatim, `comm` its cumulative
-    totals, `wire_floats` the measured-aware delta since the previous
-    record), and the per-worker step budgets / EMA throughput when a
+    wall-clock split (compile, of it lowering / execute / certificate /
+    the solver's own host time), the wire plan (`hops` is
+    `CommTracer.per_hop()` verbatim, `comm` its cumulative totals,
+    `wire_floats` the measured-aware delta since the previous record),
+    and the per-worker step budgets / EMA throughput when a
     `runtime.straggler.ThroughputTracker` is attached.
 
 `validate_record` is the schema gate: the JSONL files `obs.events.
@@ -35,38 +40,12 @@ from typing import Any, Optional, Sequence, Tuple
 
 import numpy as np
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 
 # ----------------------------------------------------------------------------
 # metric primitives
 # ----------------------------------------------------------------------------
-
-class Counter:
-    """Monotone event count (records emitted, rounds run, floats moved)."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.value = 0
-
-    def inc(self, amount: int = 1) -> int:
-        if amount < 0:
-            raise ValueError(f"counter {self.name!r} cannot decrease")
-        self.value += amount
-        return self.value
-
-
-class Gauge:
-    """Last-observed value (current gap, current round latency)."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.value: Optional[float] = None
-
-    def set(self, value: float) -> float:
-        self.value = float(value)
-        return self.value
-
 
 class Histogram:
     """Sample distribution with exact percentiles.
@@ -109,8 +88,38 @@ class Histogram:
 
 
 # ----------------------------------------------------------------------------
-# fenced timing
+# spans and fenced timing
 # ----------------------------------------------------------------------------
+
+class span:
+    """Host span: `with span("cocoa_round", step=t) as s: ...`.
+
+    Enters a `jax.profiler.TraceAnnotation(name, **attrs)` (a
+    `StepTraceAnnotation` when `step` is given), so the span lies in any
+    active profiler trace, nested, on the clock of the device planes; and
+    reads `time.perf_counter()` at entry and exit, so `s.seconds` holds
+    its length whether or not a trace is running. With the profiler off
+    a span costs about a microsecond."""
+
+    def __init__(self, name: str, *, step: Optional[int] = None, **attrs):
+        import jax
+        if step is None:
+            self._annotation = jax.profiler.TraceAnnotation(name, **attrs)
+        else:
+            self._annotation = jax.profiler.StepTraceAnnotation(
+                name, step_num=step, **attrs)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        return False
+
 
 def fenced_call(fn, *args, **kwargs):
     """Run `fn(*args)` and return `(out, seconds)` with the clock read
@@ -137,15 +146,27 @@ def fenced_time(fn, *args, iters: int = 3, warmup: int = 1, **kwargs):
     return total / max(iters, 1)
 
 
-def aot_compile(jit_fn, *args):
+def aot_stages(jit_fn, *args, what: str = "program"):
+    """AOT-compile `jit_fn` for `args` under two spans tagged `what`:
+    `cocoa_lower` (Python tracing and lowering) and `cocoa_compile` (the
+    backend compile, or a load from the persistent compile cache).
+    Returns `(runnable, lower_s, compile_s)`. A lowering or compile
+    error (a kernel the device's compiler refuses) is raised here, on
+    the first attempt."""
+    with span("cocoa_lower", what=what) as lo:
+        lowered = jit_fn.trace(*args).lower()
+    with span("cocoa_compile", what=what) as co:
+        compiled = lowered.compile()
+    return compiled, lo.seconds, co.seconds
+
+
+def aot_compile(jit_fn, *args, what: str = "program"):
     """Split trace+compile out of execution: returns `(runnable,
-    compile_s)` where `runnable(*args)` is the AOT-compiled executable
-    and `compile_s` the one-time lowering+compile wall-clock. A lowering
-    or compile error (a kernel the device's compiler refuses) is raised
-    here, on the first attempt."""
-    t0 = time.perf_counter()
-    compiled = jit_fn.lower(*args).compile()
-    return compiled, time.perf_counter() - t0
+    seconds)` where `runnable(*args)` is the AOT-compiled executable and
+    `seconds` the one-time lowering + compile wall-clock (`aot_stages`
+    keeps the two apart)."""
+    compiled, lower_s, compile_s = aot_stages(jit_fn, *args, what=what)
+    return compiled, lower_s + compile_s
 
 
 # ----------------------------------------------------------------------------
@@ -163,9 +184,11 @@ _SCHEMA: dict = {
     "gap": _NUMERIC,
     "primal": _NUMERIC,
     "dual": _NUMERIC,
-    "compile_s": _NUMERIC,
+    "compile_s": _NUMERIC,          # lowering + compile (or cache load)
+    "lower_s": _NUMERIC,            # of compile_s: tracing and lowering
     "execute_s": _NUMERIC,
     "certificate_s": _NUMERIC,
+    "host_s": _NUMERIC,             # the solver's own host time
     "wire_floats": (int,),
     "wire_bytes": (int,),
     "hops": (list, tuple),
@@ -187,7 +210,13 @@ class RoundRecord:
     so per-round *measured* volume (hier compressed gather) is visible
     round by round, not only as a running sum. `execute_s` sums the
     fenced round-step times since the previous record; `compile_s` is
-    nonzero only on the record that paid a trace+compile."""
+    nonzero only on the record that paid a trace+compile, and `lower_s`
+    is its tracing-and-lowering part (the rest is the backend compile or
+    the compile-cache load). `host_s` is the host time of the `solve`
+    call since the previous record (since entry, for the first) spent
+    outside lowering, compiling, the rounds, the certificates and the
+    caller's `on_round` hook: preparation, placement, record building
+    and the loop's own lines."""
     round: int
     round_global: int
     rounds_in_record: int
@@ -195,8 +224,10 @@ class RoundRecord:
     primal: float
     dual: float
     compile_s: float
+    lower_s: float
     execute_s: float
     certificate_s: float
+    host_s: float
     wire_floats: int
     wire_bytes: int
     hops: Tuple[dict, ...]
@@ -233,7 +264,7 @@ def validate_record(d: Any) -> dict:
     """Schema gate for one record dict; returns it or raises ValueError
     with the first violation. Checks the version, every field's presence
     and type, the per-hop row shape, and internal consistency
-    (bytes = 4 * floats, comm totals keys)."""
+    (bytes = 4 * floats, lower_s <= compile_s, comm totals keys)."""
     if not isinstance(d, dict):
         raise ValueError(f"record must be a dict, got {type(d).__name__}")
     unknown = set(d) - set(_SCHEMA)
@@ -252,9 +283,12 @@ def validate_record(d: Any) -> dict:
         raise ValueError("round and rounds_in_record must be >= 1")
     if d["round_global"] < d["round"]:
         raise ValueError("round_global cannot trail the in-call round")
-    for t_key in ("compile_s", "execute_s", "certificate_s"):
+    for t_key in ("compile_s", "lower_s", "execute_s", "certificate_s",
+                  "host_s"):
         if not np.isfinite(d[t_key]) or d[t_key] < 0:
             raise ValueError(f"{t_key} must be finite and >= 0")
+    if d["lower_s"] > d["compile_s"]:
+        raise ValueError("lower_s cannot exceed compile_s")
     if d["wire_bytes"] != 4 * d["wire_floats"]:
         raise ValueError("wire_bytes must be 4 * wire_floats")
     for row in d["hops"]:

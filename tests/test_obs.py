@@ -17,9 +17,9 @@ import pytest
 from repro import comm
 from repro.core import CoCoAConfig, solve
 from repro.data import load, partition
-from repro.obs import (Aggregator, Counter, Dashboard, EventBus, Gauge,
-                       Histogram, JsonlSink, RoundRecord, SCHEMA_VERSION,
-                       fenced_call, sparkline, validate_record)
+from repro.obs import (Aggregator, Dashboard, EventBus, Histogram,
+                       JsonlSink, RoundRecord, SCHEMA_VERSION, fenced_call,
+                       sparkline, validate_record)
 from repro.obs.validate import validate_file
 
 
@@ -33,7 +33,9 @@ def make_record(round=1, round_global=None, gap=0.5, execute_s=1e-3,
         round=round, round_global=round_global or round,
         rounds_in_record=kw.pop("rounds_in_record", 1), gap=gap,
         primal=gap + 0.1, dual=0.1, compile_s=kw.pop("compile_s", 0.0),
-        execute_s=execute_s, certificate_s=kw.pop("certificate_s", 1e-4),
+        lower_s=kw.pop("lower_s", 0.0), execute_s=execute_s,
+        certificate_s=kw.pop("certificate_s", 1e-4),
+        host_s=kw.pop("host_s", 2e-5),
         wire_floats=wire, wire_bytes=4 * wire, hops=hops,
         comm={"comm_vectors": 4 * round, "comm_floats": 256 * round,
               "comm_bytes": 1024 * round, "comm_psums": round}, **kw)
@@ -57,9 +59,9 @@ def test_record_golden_key_order():
     the golden files CI diffs rely on it being stable across runs."""
     keys = list(make_record().to_dict())
     assert keys == ["schema", "round", "round_global", "rounds_in_record",
-                    "gap", "primal", "dual", "compile_s", "execute_s",
-                    "certificate_s", "wire_floats", "wire_bytes", "hops",
-                    "comm", "budgets", "throughput"]
+                    "gap", "primal", "dual", "compile_s", "lower_s",
+                    "execute_s", "certificate_s", "host_s", "wire_floats",
+                    "wire_bytes", "hops", "comm", "budgets", "throughput"]
     assert make_record().to_dict()["schema"] == SCHEMA_VERSION
 
 
@@ -73,6 +75,10 @@ def test_record_golden_key_order():
     (lambda d: d.update(round_global=0), "round_global"),
     (lambda d: d.update(execute_s=-1.0), "finite and >= 0"),
     (lambda d: d.update(execute_s=float("nan")), "finite and >= 0"),
+    (lambda d: d.update(compile_s=0.5, lower_s=0.75), "cannot exceed"),
+    (lambda d: d.update(lower_s=-1e-3), "finite and >= 0"),
+    (lambda d: d.update(host_s=-1e-3), "finite and >= 0"),
+    (lambda d: d.pop("host_s"), "missing field"),
     (lambda d: d.update(wire_bytes=1), "4 \\* wire_floats"),
     (lambda d: d.update(hops=[{"hop": "reduce"}]), "hop row missing"),
     (lambda d: d.update(comm={}), "comm totals missing"),
@@ -110,13 +116,6 @@ def test_validate_file_catches_bad_line_and_regression(tmp_path):
 # ----------------------------------------------------------------------------
 
 def test_primitives():
-    c = Counter("n")
-    assert c.inc() == 1 and c.inc(4) == 5
-    with pytest.raises(ValueError):
-        c.inc(-1)
-    g = Gauge("gap")
-    assert g.value is None and g.set(0.25) == 0.25
-
     h = Histogram("lat")
     samples = [0.4, 0.1, 0.9, 0.2, 0.7, 0.3]
     for s in samples:

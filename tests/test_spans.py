@@ -1,0 +1,110 @@
+"""Host spans inside `solve` and the certificate's device scopes.
+
+`solve` runs under the host spans `cocoa_solve` > `cocoa_lower`,
+`cocoa_compile`, `cocoa_place`, `cocoa_round`, `cocoa_certificate`,
+`cocoa_record`, `cocoa_on_round` (`obs.metrics.span`), and its records
+carry their totals: `compile_s` with its `lower_s` part, `execute_s`,
+`certificate_s` and the solver's own `host_s`. The certificate labels its
+passes `rmatvec`, `primal` and `dual` under `cocoa/certificate`. How a
+profiler trace shows them is tested beside the benchmark's trace reader
+(`bench/tests/test_bench_spans.py`).
+"""
+import functools
+import re
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import CoCoAConfig, duality, solve
+from repro.core.cocoa import _scoped
+from repro.core.losses import get_loss
+from repro.data import load, partition, partition_sparse
+from repro.obs import (Aggregator, EventBus, aot_compile, aot_stages, span,
+                       validate_record)
+
+K = 4
+SCOPES = ("cocoa/certificate/rmatvec", "cocoa/certificate/primal",
+          "cocoa/certificate/dual")
+
+
+def _data(kind):
+    if kind == "dense":
+        X, y = load("tiny")
+        return partition(X, y, K, seed=0)
+    csr, y = load("tiny_sparse")
+    return partition_sparse(csr, y, K, seed=0)
+
+
+def _solve(kind, rounds=4, hook_s=0.0):
+    """A small vmap solve with a bus and a hook that sleeps `hook_s`;
+    returns (records, wall seconds of the call, seconds in the hook)."""
+    X, y, mask = _data(kind)
+    cfg = CoCoAConfig.adding(K, loss="hinge", lam=1e-3, H=32)
+    bus = EventBus()
+    agg = bus.subscribe(Aggregator())
+    in_hook = []
+
+    def hook(t, state, gap):
+        t0 = time.perf_counter()
+        time.sleep(hook_s)
+        in_hook.append(time.perf_counter() - t0)
+
+    t0 = time.perf_counter()
+    solve(cfg, X, y, mask, rounds=rounds, gap_every=1, seed=0, obs=bus,
+          on_round=hook)
+    return agg.records, time.perf_counter() - t0, sum(in_hook)
+
+
+def test_span_times_and_annotates():
+    with span("outer", what="x") as s:
+        time.sleep(0.01)
+    assert 0.01 <= s.seconds < 1.0
+    with pytest.raises(KeyError):
+        with span("raises") as s2:
+            raise KeyError("propagates")
+    assert s2.seconds >= 0
+
+
+def test_aot_compile_is_its_two_stages():
+    compiled, lower_s, compile_s = aot_stages(jax.jit(lambda x: x + 1),
+                                              np.ones(3, np.float32),
+                                              what="probe")
+    assert lower_s > 0 and compile_s > 0
+    assert float(compiled(np.ones(3, np.float32))[0]) == 2.0
+    compiled, seconds = aot_compile(jax.jit(lambda x: 2 * x),
+                                    np.ones(3, np.float32))
+    assert seconds > 0 and float(compiled(np.ones(3, np.float32))[1]) == 2.0
+
+
+def test_records_partition_the_call():
+    records, wall, hook = _solve("dense", rounds=5, hook_s=0.002)
+    assert len(records) == 5
+    for rec in records:
+        validate_record(rec.to_dict())
+        assert 0 <= rec.lower_s <= rec.compile_s
+        assert rec.host_s >= 0
+    assert records[0].lower_s > 0
+    assert all(r.compile_s == r.lower_s == 0 for r in records[1:])
+    accounted = sum(r.compile_s + r.execute_s + r.certificate_s + r.host_s
+                    for r in records) + hook
+    assert abs(accounted - wall) <= 0.05 * wall + 0.005
+
+
+@pytest.mark.parametrize("kind", ["dense", "ell"])
+@pytest.mark.parametrize("gap", ["gap_decomposed", "gap_at_v"])
+def test_lowered_certificate_op_names(kind, gap):
+    X, y, mask = _data(kind)
+    fn = functools.partial(getattr(duality, gap), loss=get_loss("hinge"),
+                           lam=1e-3, reg=duality.L2)
+    alpha = np.zeros(y.shape, np.float32)
+    args = (alpha, X, y, mask)
+    if gap == "gap_at_v":
+        args = (np.zeros(X.d if kind == "ell" else X.shape[-1],
+                         np.float32),) + args
+    text = jax.jit(_scoped("cocoa/certificate", fn)).trace(*args) \
+        .lower().compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in SCOPES:
+        assert any(scope in op for op in op_names), (scope, gap, kind)
