@@ -68,8 +68,8 @@ from . import duality
 from .accel import AccelSpec, init_accel_state, parse_accel, wrap_round
 from .losses import Loss, get_loss
 from .regularizers import L2, Regularizer, get_regularizer
-from .solvers import (LocalSolver, SDCAResult, SOLVERS, get_solver,
-                      sparse_counterpart)
+from .solvers import (LocalSolver, SDCAResult, SOLVERS, ell_width,
+                      get_solver, local_sdca_sparse, sparse_counterpart)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -315,13 +315,16 @@ def make_round_vmap(cfg: CoCoAConfig, K: int,
         # the named scopes label the solver vs. exchange regions in a
         # jax.profiler trace (obs.ProfilerSink) -- no-ops otherwise
         with jax.named_scope("cocoa/local_solve"):
-            if budget is None:
-                res = jax.vmap(lambda Xk, yk, ak, mk, r: body(Xk, yk, ak, mk, state.w, r)
-                               )(X, y, alpha_split(state.alpha, K), mask, rngs)
-            else:
-                res = jax.vmap(lambda Xk, yk, ak, mk, r, b: body(
-                    Xk, yk, ak, mk, state.w, r, budget=b)
-                )(X, y, alpha_split(state.alpha, K), mask, rngs, budget)
+            per_worker = {} if budget is None else {"budget": budget}
+            if solver.fn is local_sdca_sparse:
+                # the norms of the rows as given: summed over the added
+                # zeros they could round differently
+                per_worker["sqnorms"] = jnp.sum(X.vals * X.vals,
+                                                axis=-1) * mask
+                X = X.widened(ell_width(X.r_max, K))
+            res = jax.vmap(lambda Xk, yk, ak, mk, r, kw: body(
+                Xk, yk, ak, mk, state.w, r, **kw)
+            )(X, y, alpha_split(state.alpha, K), mask, rngs, per_worker)
         # --- the communication step: damp, compress, reduce, apply ---
         with jax.named_scope("cocoa/exchange"):
             crngs = jax.vmap(comm.comm_rng)(rngs)
@@ -585,6 +588,20 @@ class SolveResult(NamedTuple):
                     # over the emitted RoundRecords (obs.Aggregator.history)
 
 
+def _ell_attrs(cfg: CoCoAConfig, X) -> dict:
+    """`cocoa_solve`'s attributes on ELL data that `local_sdca_sparse`
+    solves: the width it runs at and the padding slots the vmap backend
+    adds to every row for it. Empty otherwise."""
+    if not isinstance(X, (SparseShards, FeatureShards)):
+        return {}
+    if _resolve_solver(cfg.solver, sparse=True).fn is not local_sdca_sparse:
+        return {}
+    r_max = int(X.cols.shape[-1])
+    width = (r_max if cfg.backend == "shard_map"
+             else ell_width(r_max, int(X.cols.shape[0])))
+    return {"ell_width": width, "ell_pad": width - r_max}
+
+
 def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
           seed: int = 0, gap_every: int = 1, mesh=None, budget_fn=None,
           on_round: Optional[Callable[[int, CoCoAState, float], None]] = None,
@@ -609,7 +626,9 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     rates and the budgets land in the records.
 
     The call runs under host spans (`obs.metrics.span`), which land in
-    any active `jax.profiler` trace: `cocoa_solve` around it all, and
+    any active `jax.profiler` trace: `cocoa_solve` around it all (on ELL
+    data that `sdca_sparse` solves, with attributes `ell_width` and
+    `ell_pad`: the width it runs at and the slots added to reach it), and
     inside it `cocoa_lower` / `cocoa_compile` (tagged `what=round` or
     `what=certificate`), `cocoa_place` (the state's host copy and the
     data's placement), `cocoa_round` per round, `cocoa_certificate`,
@@ -622,7 +641,7 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     along its feature axis to match (padded coordinates carry no data and
     stay exactly zero).
     """
-    with span("cocoa_solve"):
+    with span("cocoa_solve", **_ell_attrs(cfg, X)):
         # host_s bookkeeping: the clock since the last record (since entry,
         # for the first), less what the lowering, compile, round,
         # certificate and hook spans below held

@@ -255,6 +255,26 @@ def local_sdca_importance(X_k, y_k, alpha_k, mask_k, v, rng, loss, lam, n,
     return SDCAResult(dalpha, u - v, jnp.asarray(H))
 
 
+# XLA's TPU compiler lowers a scatter-add that vmap batches over K workers
+# (local_sdca_sparse's `u.at[ci].add` under the vmap backend) to one scatter
+# on the flattened (K * d) vector only when it makes at least this many
+# updates, K * r_max; with fewer it scatters into the tiled (K, d) array at
+# about three times the cost per update (PERF.md, §6).
+_FLAT_SCATTER_UPDATES = 1024
+
+
+def ell_width(r_max: int, workers: int) -> int:
+    """The ELL width the vmap backend runs `local_sdca_sparse` at, for
+    `workers` workers' rows of `r_max` slots: the least width whose batched
+    scatter-add XLA flattens, where reaching it takes at most 3.5 times the
+    slots (beyond that the flat form's extra updates cost more than they
+    save); `r_max` otherwise. One worker's scatter is flat at any width."""
+    flat = -(-_FLAT_SCATTER_UPDATES // workers)
+    if workers > 1 and r_max < flat <= 3.5 * r_max:
+        return flat
+    return r_max
+
+
 def local_sdca_sparse(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
                       lam: float, n, sigma_p: float, H: int,
                       sqnorms=None, model_axis=None,
@@ -270,8 +290,15 @@ def local_sdca_sparse(shard, y_k, alpha_k, mask_k, v, rng, loss: Loss,
     stays O(nnz) for every regularizer (identity under L2, bit-for-bit).
 
     This is the portable jnp fallback for the Pallas kernel in
-    repro.kernels.sparse_sdca; padding slots (col 0, val 0) are exact
-    arithmetic no-ops, so no per-row nnz bookkeeping is needed here.
+    repro.kernels.sparse_sdca; padding slots (col 0, val 0.0) are exact
+    arithmetic no-ops at any width -- each adds 0.0 * x into u[0] and 0.0
+    to the dot -- so no per-row nnz bookkeeping is needed here.
+
+    Width: the vmap backend hands this solver its K workers' rows widened
+    to `ell_width(r_max, K)` slots with more such padding, so that XLA
+    lowers the per-step scatter-add, batched over the workers, to its flat
+    form (see `ell_width`). Unbatched, as under shard_map, the scatter is
+    flat at any width and the rows run as they are.
 
     `model_axis`: feature-sharded mode -- the shard's `cols` are
     *shard-local* column ids into the local v slice (d_local floats, see

@@ -245,6 +245,20 @@ class SparseShards:
     def r_max(self) -> int:
         return self.cols.shape[-1]
 
+    def widened(self, r_max: int) -> "SparseShards":
+        """The same rows with `r_max` slots each, the added slots padding
+        (column 0, value 0.0); a shard that wide already comes back as
+        it is."""
+        pad = r_max - self.r_max
+        if pad < 0:
+            raise ValueError(f"cannot widen {self.r_max} ELL slots to "
+                             f"{r_max}")
+        if pad == 0:
+            return self
+        widths = ((0, 0),) * (self.cols.ndim - 1) + ((0, pad),)
+        return SparseShards(jnp.pad(self.cols, widths),
+                            jnp.pad(self.vals, widths), self.nnz, d=self.d)
+
     @property
     def density(self) -> float:
         rows = int(np.prod(self.nnz.shape))

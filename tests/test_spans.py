@@ -10,6 +10,9 @@ profiler trace shows them is tested beside the benchmark's trace reader
 (`bench/tests/test_bench_spans.py`).
 """
 import functools
+import glob
+import os
+import pathlib
 import re
 import time
 
@@ -18,7 +21,8 @@ import numpy as np
 import pytest
 
 from repro.core import CoCoAConfig, duality, solve
-from repro.core.cocoa import _scoped
+from repro.core.cocoa import _ell_attrs, _scoped
+from repro.core.solvers import ell_width
 from repro.core.losses import get_loss
 from repro.data import load, partition, partition_sparse
 from repro.obs import (Aggregator, EventBus, aot_compile, aot_stages, span,
@@ -108,3 +112,48 @@ def test_lowered_certificate_op_names(kind, gap):
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in SCOPES:
         assert any(scope in op for op in op_names), (scope, gap, kind)
+
+
+@pytest.mark.parametrize("kind", ["dense", "ell"])
+def test_solve_span_carries_the_ell_width(kind, tmp_path):
+    """On ELL data `cocoa_solve` carries the width the sparse solver runs
+    at and the slots the vmap backend adds to reach it; on dense data
+    neither."""
+    if kind == "dense":
+        X, y, mask = _data("dense")
+    else:
+        csr, y = load("tiny_sparse")
+        X, y, mask = partition_sparse(csr, y, K, seed=0, r_max=100)
+    cfg = CoCoAConfig.adding(K, loss="hinge", lam=1e-3, H=32)
+    with jax.profiler.trace(str(tmp_path)):
+        solve(cfg, X, y, mask, rounds=1, seed=0)
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_serialized_xspace(
+        pathlib.Path(path).read_bytes())
+    stats = [dict(ev.stats) for plane in pd.planes
+             if plane.name.startswith("/host:CPU")
+             for line in plane.lines for ev in line.events
+             if ev.name == "cocoa_solve"]
+    assert len(stats) == 1
+    if kind == "dense":
+        assert not {"ell_width", "ell_pad"} & set(stats[0])
+    else:
+        # K = 4 workers of 100 slots: 256 slots make 1024 updates a step
+        assert stats[0]["ell_width"] == 256
+        assert stats[0]["ell_pad"] == 156
+
+
+def test_ell_attrs_only_where_the_jnp_solver_runs():
+    X, _, _ = _data("ell")
+    r_max = X.cols.shape[-1]
+    want = {"ell_width": ell_width(r_max, K),
+            "ell_pad": ell_width(r_max, K) - r_max}
+    assert _ell_attrs(CoCoAConfig.adding(K), X) == want
+    assert _ell_attrs(CoCoAConfig.adding(K, solver="sdca_sparse"), X) == want
+    # unbatched under shard_map: the rows run as they are
+    assert _ell_attrs(CoCoAConfig.adding(K, backend="shard_map"), X) == {
+        "ell_width": r_max, "ell_pad": 0}
+    # the Pallas kernel walks the shard's own slots
+    assert _ell_attrs(CoCoAConfig.adding(K, solver="sdca_kernel"), X) == {}
+    assert _ell_attrs(CoCoAConfig.adding(K), _data("dense")[0]) == {}

@@ -2,6 +2,11 @@
 partitioner parity with the dense contract, sparse duality-gap evaluation,
 and the Pallas sparse LocalSDCA kernel vs its pure-jnp oracle (bit-for-bit,
 same visit order -- not statistical)."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +14,7 @@ import pytest
 
 from repro.core import CoCoAConfig, duality, solve
 from repro.core.losses import get_loss
-from repro.core.solvers import local_sdca, local_sdca_sparse
+from repro.core.solvers import ell_width, local_sdca, local_sdca_sparse
 from repro.data import sparse as sp
 from repro.data.synthetic import partition
 from repro.kernels.ops import sparse_local_sdca_block
@@ -418,6 +423,162 @@ def test_sparse_jnp_solver_matches_dense_solver():
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(rs.du), np.asarray(rd.du),
                                rtol=2e-4, atol=2e-5)
+
+
+# ----------------------------------------------------------------------------
+# the ELL width the vmap backend runs the jnp solver at
+# ----------------------------------------------------------------------------
+
+def _shard_at_width(width, nk=128, d=256, density=0.1, seed=23, K=1):
+    """ELL shards whose slot axis is `width` wide (K workers; K=1 gives one
+    worker's shard without the leading axis)."""
+    csr, y = sp.make_sparse_classification(nk * K, d, density=density,
+                                           seed=seed)
+    sh, yp, mk = sp.partition_sparse(csr, y, K, seed=seed + 1, r_max=width)
+    assert sh.cols.shape == (K, nk, width)
+    if K > 1:
+        return sh, yp, mk
+    rng = np.random.default_rng(seed + 2)
+    w = jnp.asarray((rng.standard_normal(d) * 0.01).astype(np.float32))
+    shard = jax.tree.map(lambda a: a[0], sh)
+    return shard, yp[0], jnp.zeros(nk), mk[0], w
+
+
+@pytest.mark.parametrize("r_max,workers,width", [
+    (122, 8, 128), (128, 8, 128), (129, 8, 129), (256, 8, 256),
+    (42, 8, 128), (36, 8, 36), (122, 4, 256), (63, 16, 64),
+    (122, 16, 122), (122, 1, 122), (122, 2, 122), (200, 2, 512)])
+def test_ell_width_reaches_the_flat_scatter(r_max, workers, width):
+    """K * width reaches the 1024 updates at which XLA flattens the batched
+    scatter-add, unless that takes more than 3.5 times the slots; one
+    worker runs as it is."""
+    assert ell_width(r_max, workers) == width
+
+
+def test_widened_shard_adds_padding_slots():
+    shard, *_ = _shard_at_width(122)
+    wide = shard.widened(128)
+    assert wide.cols.shape == wide.vals.shape == (128, 128)
+    np.testing.assert_array_equal(np.asarray(wide.cols[:, :122]),
+                                  np.asarray(shard.cols))
+    np.testing.assert_array_equal(np.asarray(wide.vals[:, :122]),
+                                  np.asarray(shard.vals))
+    # the added slots are the ELL padding slot: column 0, value 0.0
+    assert not np.any(np.asarray(wide.cols[:, 122:]))
+    assert not np.any(np.asarray(wide.vals[:, 122:]))
+    assert wide.nnz is shard.nnz and wide.d == shard.d
+    assert shard.widened(122) is shard
+    with pytest.raises(ValueError, match="cannot widen"):
+        shard.widened(121)
+
+
+@pytest.mark.parametrize("loss_name", ["hinge", "smooth_hinge1", "squared"])
+def test_sparse_solver_widened_shard_matches_unpadded(loss_name):
+    """A width-122 shard widened to 128 gives what it gives at 122, to
+    float32 rounding: the six added slots per row are no-ops."""
+    loss = get_loss(loss_name)
+    shard, y, a, m, w = _shard_at_width(122)
+    rng = jax.random.PRNGKey(5)
+    # the row norms of the rows as given, as the vmap backend passes them
+    sq = jnp.sum(shard.vals * shard.vals, axis=-1) * m
+    wide = local_sdca_sparse(shard.widened(128), y, a, m, w, rng, loss,
+                             1e-3, 128.0, 4.0, 512, sqnorms=sq)
+    res = local_sdca_sparse(shard, y, a, m, w, rng, loss, 1e-3, 128.0, 4.0,
+                            512)
+    assert float(jnp.max(jnp.abs(res.dalpha))) > 0
+    np.testing.assert_allclose(np.asarray(wide.dalpha),
+                               np.asarray(res.dalpha), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(wide.du), np.asarray(res.du),
+                               rtol=1e-6)
+
+
+def test_sparse_solver_masked_rows_are_noops_at_widened_width():
+    """Masked rows move nothing at the widened width: their dalpha stays
+    exactly 0, and what they hold does not reach the result."""
+    loss = get_loss("smooth_hinge1")
+    shard, y, a, m, w = _shard_at_width(122)
+    m = m.at[-40:].set(0.0)
+    noisy = sp.SparseShards(
+        shard.cols, shard.vals.at[-40:].set(7.0), shard.nnz, d=shard.d)
+    rng = jax.random.PRNGKey(6)
+    r1 = local_sdca_sparse(shard.widened(128), y, a, m, w, rng, loss, 1e-3,
+                           128.0, 4.0, 512)
+    r2 = local_sdca_sparse(noisy.widened(128), y, a, m, w, rng, loss, 1e-3,
+                           128.0, 4.0, 512)
+    assert float(jnp.max(jnp.abs(r1.dalpha[-40:]))) == 0.0
+    assert float(jnp.max(jnp.abs(r1.dalpha))) > 0
+    np.testing.assert_array_equal(np.asarray(r1.dalpha), np.asarray(r2.dalpha))
+    np.testing.assert_array_equal(np.asarray(r1.du), np.asarray(r2.du))
+
+
+@pytest.mark.parametrize("width", [122, 128])
+def test_vmap_round_widens_only_short_rows(width, monkeypatch):
+    """K = 8 workers: rows of 122 slots run at 128 and agree with the same
+    solver at 122 to float32 rounding; rows of 128 slots run as they are,
+    bit for bit. The reference is local_sdca_sparse registered under
+    another solver, which the backend never widens."""
+    from repro.core import cocoa, solvers
+    as_given = solvers.LocalSolver(
+        "sdca_sparse_as_given",
+        lambda *a, **kw: solvers.local_sdca_sparse(*a, **kw),
+        dense=False, sparse=True, model_axis=True, sqnorms=True)
+    monkeypatch.setitem(solvers.SOLVERS, as_given.name, as_given)
+    X, y, mask = _shard_at_width(width, nk=32, K=8)
+    state0 = cocoa.init_state(X.d, 8, 32, 3, jnp.float32)
+    out = {}
+    for name in ("sdca", as_given.name):
+        cfg = CoCoAConfig.adding(8, loss="smooth_hinge", lam=1e-3, H=64,
+                                 solver=name)
+        round_fn = cocoa.make_round_vmap(cfg, 8)
+        jaxpr = str(jax.make_jaxpr(round_fn)(state0, X, y, mask))
+        out[name] = (jax.jit(round_fn)(state0, X, y, mask), " pad[" in jaxpr)
+    (ours, widened), (ref, ref_widened) = out["sdca"], out[as_given.name]
+    assert widened == (width == 122) and not ref_widened
+    assert float(jnp.max(jnp.abs(ref.alpha))) > 0
+    for a, b in ((ours.alpha, ref.alpha), (ours.w, ref.w)):
+        if width == 128:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-9)
+
+
+def test_feature_sharded_unaligned_width_matches_vmap():
+    """On a (2, 2) CPU mesh the feature-sharded solve runs its shard-local
+    ELL slices as they are (widths not lane multiples, unbatched) and
+    matches the vmap reference, which widens its 200 slots to 512, to the
+    mesh tests' 1e-6."""
+    code = """
+        import jax, jax.numpy as jnp
+        from repro.core import CoCoAConfig, solve
+        from repro.core.solvers import ell_width
+        from repro.data import load
+        from repro.data.sparse import partition_sparse
+        csr, y = load("tiny_sparse")
+        sh, yp, mk = partition_sparse(csr, y, 2, seed=0, r_max=200)
+        fs, _, _ = partition_sparse(csr, y, 2, seed=0, M=2)
+        assert ell_width(sh.r_max, 2) == 512 and fs.r_loc % 128
+        kw = dict(loss="smooth_hinge", lam=1e-3, H=128)
+        rv = solve(CoCoAConfig.adding(2, **kw), sh, yp, mk, rounds=3,
+                   gap_every=1)
+        rs = solve(CoCoAConfig.adding(2, backend="shard_map",
+                                      model_axis="model", **kw),
+                   fs, yp, mk, rounds=3, gap_every=1,
+                   mesh=jax.make_mesh((2, 2), ("data", "model")))
+        w_err = float(jnp.max(jnp.abs(rs.state.w[:sh.d] - rv.state.w)))
+        a_err = float(jnp.max(jnp.abs(rs.state.alpha - rv.state.alpha)))
+        assert w_err < 1e-6 and a_err < 1e-6, (w_err, a_err)
+        assert float(jnp.max(jnp.abs(rv.state.alpha))) > 0
+        print("UNALIGNED FEATURE-SHARDED OK")
+    """
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))), "src"))
+    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert "UNALIGNED FEATURE-SHARDED OK" in p.stdout
 
 
 @pytest.mark.parametrize("solver", ["sdca", "sdca_kernel"])

@@ -20,6 +20,7 @@ import pytest
 from repro.core import CoCoAConfig
 from repro.core.cocoa import init_state, make_round_vmap
 from repro.core.losses import get_loss
+from repro.core.solvers import _FLAT_SCATTER_UPDATES, ell_width
 from repro.data.sparse import SparseShards
 from repro.kernels.local_sdca import local_sdca_pallas
 from repro.kernels.sparse_sdca import sparse_local_sdca, sparse_local_sdca_zx
@@ -126,3 +127,39 @@ def test_jnp_round_compiles_for_v5e_rcv1_shape(spec):
     assert time.perf_counter() - t0 < 60
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _has_flat_scatter(hlo_text: str, size: int) -> bool:
+    return any(" scatter(" in ln and f"f32[{size}]" in ln
+               for ln in hlo_text.splitlines())
+
+
+@pytest.mark.parametrize("workers,width", [(8, 127), (8, 128), (4, 255),
+                                           (4, 256), (16, 63), (16, 64)])
+def test_batched_scatter_add_flattens_at_1024_updates(spec, workers, width):
+    """The compiler fact `solvers.ell_width` rests on: a vmap-batched
+    `u.at[c].add(v)` becomes one scatter over the flattened (K * d)
+    vector exactly when K * width reaches 1024 updates."""
+    d = RCV1["d"]
+    fn = jax.vmap(lambda u, c, v: u.at[c].add(v))
+    text = jax.jit(fn).lower(spec((workers, d)),
+                             spec((workers, width), jnp.int32),
+                             spec((workers, width))).compile().as_text()
+    assert _has_flat_scatter(text, workers * d) == (
+        workers * width >= _FLAT_SCATTER_UPDATES)
+
+
+def test_jnp_round_scatter_is_flat_at_rcv1_cell_width(spec):
+    """The rcv1 cell's rows of 122 slots run widened to 128: the round's
+    per-step scatter-add is the flat one."""
+    S = spec
+    nk, d, r_max = 4_096, RCV1["d"], 122
+    assert ell_width(r_max, K) == 128
+    X = SparseShards(S((K, nk, r_max), jnp.int32), S((K, nk, r_max)),
+                     S((K, nk), jnp.int32), d=d)
+    state = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_state(d, K, nk)))
+    cfg = CoCoAConfig.adding(K, loss="smooth_hinge", lam=1e-4, H=nk)
+    text = jax.jit(make_round_vmap(cfg, K)).lower(
+        state, X, S((K, nk)), S((K, nk))).compile().as_text()
+    assert _has_flat_scatter(text, K * d)
