@@ -184,16 +184,21 @@ class Topology:
 
     def all_sum(self, x):
         """Cross-worker sum routed per the reduce kind. Simulated flavor:
-        `x` carries the leading K axis and the sum happens on the driver;
-        mesh flavor: `x` is the per-worker value inside shard_map. Every
-        kind returns the same total (to fp association)."""
-        if self.reduce == "hier":
-            return self._hier_sum(x)
-        if self.reduce == "a2a":
-            return self._a2a_sum(x)
-        if self.is_mesh:
+        `x` carries the leading K axis and the sum is an array op over it
+        (a2a sums each worker's chunk, which is the flat sum elementwise);
+        mesh flavor: `x` is the per-worker value inside shard_map, and the
+        collectives run under the named scope `all_reduce`, so a trace
+        times them apart from the rest of the exchange. Every kind returns
+        the same total (to fp association)."""
+        if not self.is_mesh:
+            return (self._hier_sum(x) if self.reduce == "hier"
+                    else jnp.sum(x, axis=0))
+        with jax.named_scope("all_reduce"):
+            if self.reduce == "hier":
+                return self._hier_sum(x)
+            if self.reduce == "a2a":
+                return self._a2a_sum(x)
             return jax.lax.psum(x, self.data_axes)
-        return jnp.sum(x, axis=0)
 
     # -- hierarchical (two-level) reduce ------------------------------------
 
@@ -245,10 +250,6 @@ class Topology:
     # -- all-to-all (reduce-scatter + all-gather) ----------------------------
 
     def _a2a_sum(self, x):
-        if not self.is_mesh:
-            # each simulated worker sums its 1/K chunk, then the chunks are
-            # concatenated -- elementwise identical to the flat driver sum
-            return jnp.sum(x, axis=0)
         shape = x.shape
         xf = x.reshape(-1)
         pad = (-xf.size) % self.K

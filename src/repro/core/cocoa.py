@@ -289,20 +289,21 @@ def _worker_body(X_k, y_k, alpha_k, mask_k, v, rng, *, loss: Loss, lam: float,
 # vmap backend (simulation of K workers; exact same math as production)
 # ----------------------------------------------------------------------------
 
-def make_round_vmap(cfg: CoCoAConfig, K: int,
-                    n_total=None) -> Callable[..., CoCoAState]:
+def make_round_vmap(cfg: CoCoAConfig, K: int) -> Callable[..., CoCoAState]:
     """Simulated K-worker round. `X` may be a dense (K, nk, d) array or a
     SparseShards pytree -- vmap maps over the leading K axis of either, and
     cfg.solver is transparently mapped to its ELL counterpart for sparse
-    inputs (sdca -> sdca_sparse, sdca_kernel -> sdca_sparse_kernel)."""
+    inputs (sdca -> sdca_sparse, sdca_kernel -> sdca_sparse_kernel). `n`,
+    the number of real rows, is summed from `mask` when not given."""
     loss = get_loss(cfg.loss)
     reg = cfg.regularizer()
     topo = Topology.simulated(K, topology=cfg.topology)
     p = cfg.agg_params(K)
     compressor = cfg.compressor()
 
-    def round_fn(state: CoCoAState, X, y, mask, budget=None) -> CoCoAState:
-        n = duality.effective_n(mask) if n_total is None else n_total
+    def round_fn(state: CoCoAState, X, y, mask, n=None,
+                 budget=None) -> CoCoAState:
+        n = duality.effective_n(mask) if n is None else n
         rng, sub = jax.random.split(state.rng)
         # fold_in (not split) so worker k's stream is identical to the
         # shard_map backend's fold_in(sub, axis_index) -- backend parity is
@@ -752,13 +753,16 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
         # instead of being transferred again every round
         with span("cocoa_place", what="state"):
             state = jax.tree.map(np.asarray, state)
+        # the number of real rows is fixed for the solve: summed once here,
+        # not in every round (on a mesh that sum is an all-reduce)
+        n = duality.effective_n(mask)
         run_fn, pending_lower, load_s = aot_stages(
-            round_fn, state, X, y, mask, *extra_args(0), what="round")
+            round_fn, state, X, y, mask, n, *extra_args(0), what="round")
         pending_compile = pending_lower + load_s
         held += pending_compile
         with span("cocoa_place", what="data"):
-            X, y, mask = jax.device_put((X, y, mask),
-                                        run_fn.input_shardings[0][1:4])
+            X, y, mask, n = jax.device_put((X, y, mask, n),
+                                           run_fn.input_shardings[0][1:5])
         gap_run = None
         base_round = int(state.rounds)
         gap = float("inf")
@@ -767,7 +771,7 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
         prev_floats = 0
         for t in range(rounds):
             with span("cocoa_round", step=t) as round_span:
-                state, dt = fenced_call(run_fn, state, X, y, mask,
+                state, dt = fenced_call(run_fn, state, X, y, mask, n,
                                         *extra_args(t))
             held += round_span.seconds
             exec_acc += dt

@@ -163,3 +163,26 @@ def test_importance_sampling_helps_on_skewed_data():
     r_i = solve(CoCoAConfig.adding(K, solver="sdca_importance", **kw),
                 Xp, yp, mk, rounds=25, gap_every=25, seed=3)
     assert r_i.history["gap"][-1] < r_u.history["gap"][-1] * 1.02
+
+
+def test_solve_sums_n_once_and_hands_it_to_every_round(problem,
+                                                       monkeypatch):
+    """The number of real rows is fixed for a solve: `solve` sums the mask
+    once and passes n to each round, which then takes no sum of its own
+    (on a mesh, a scalar all-reduce a round)."""
+    from repro.core import cocoa
+    Xp, yp, mk = problem
+    K = Xp.shape[0]
+    seen, make = [], cocoa.make_round_vmap
+
+    def spy(cfg, K):
+        round_fn = make(cfg, K)
+
+        def traced(state, X, y, mask, n=None, *rest):
+            seen.append(n)
+            return round_fn(state, X, y, mask, n, *rest)
+        return traced
+    monkeypatch.setattr(cocoa, "make_round_vmap", spy)
+    solve(CoCoAConfig.adding(K, loss="hinge", lam=1e-3, H=64), Xp, yp, mk,
+          rounds=3, gap_every=3)
+    assert seen and all(n is not None for n in seen)

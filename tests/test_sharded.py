@@ -7,11 +7,19 @@ import textwrap
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# XLA:CPU runs each forced host device's part of a collective on its own
+# thread and aborts the process when one has not arrived after 40 s; on a
+# loaded machine a starved thread is late, not stuck, so the abort comes
+# only well past that, still inside `_run`'s own timeout
+COLLECTIVE_TIMEOUTS = ("--xla_cpu_collective_call_warn_stuck_timeout_seconds"
+                       "=120 --xla_cpu_collective_call_terminate_timeout_"
+                       "seconds=600")
 
 
 def _run(code: str, devices: int = 8, timeout: int = 900):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
+                        f"{COLLECTIVE_TIMEOUTS}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,

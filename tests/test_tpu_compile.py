@@ -8,8 +8,10 @@ dispatch in `kernels.ops` reads `jax.default_backend()` (the CPU here).
 
 Shard widths: rcv1 (677,399 x 47,236, ~0.16% dense, r_max 114) and
 epsilon (400,000 x 2,000, dense) split over K=8 workers, rows padded to a
-multiple of 128. The topology is described inside a fixture, never at
-import time: only the worker that runs this file loads the TPU library.
+multiple of 128; the mesh round splits rcv1 over the 2x2 host's four
+chips, one worker a chip. The topology is described inside a fixture,
+never at import time: only the worker that runs this file loads the TPU
+library.
 """
 import time
 
@@ -18,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import CoCoAConfig
-from repro.core.cocoa import init_state, make_round_vmap
+from repro.core.cocoa import init_state, make_round_sharded, make_round_vmap
 from repro.core.losses import get_loss
 from repro.core.solvers import _FLAT_SCATTER_UPDATES, ell_width
 from repro.data.sparse import SparseShards
@@ -163,3 +165,36 @@ def test_jnp_round_scatter_is_flat_at_rcv1_cell_width(spec):
     text = jax.jit(make_round_vmap(cfg, K)).lower(
         state, X, S((K, nk)), S((K, nk))).compile().as_text()
     assert _has_flat_scatter(text, K * d)
+
+
+def test_mesh_round_all_reduces_once_under_its_scope(topo):
+    """The shard_map round at the rcv1 shape, one worker on each of the
+    four chips, with n handed in as `solve` does: its one collective is
+    the exchange's all-reduce of d float32, under the named scope the
+    benchmark's `all_reduce_ms` reads."""
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    mesh = Mesh(topo.devices, ("data",), axis_types=(AxisType.Auto,))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    workers, nk, d, r_max = 4, 169_350, RCV1["d"], 122
+
+    def S(shape, dtype=jnp.float32, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    X = SparseShards(S((workers, nk, r_max), jnp.int32),
+                     S((workers, nk, r_max)), S((workers, nk), jnp.int32),
+                     d=d)
+    state = jax.tree.map(lambda a: S(a.shape, a.dtype, rep),
+                         jax.eval_shape(lambda: init_state(d, workers, nk)))
+    cfg = CoCoAConfig.adding(workers, loss="smooth_hinge", lam=1e-4, H=nk,
+                             backend="shard_map")
+    text = jax.jit(make_round_sharded(cfg, mesh)).lower(
+        state, X, S((workers, nk)), S((workers, nk)),
+        S((), sharding=rep)).compile().as_text()
+    collectives = [ln for ln in text.splitlines()
+                   if " = " in ln and any(f" {op}(" in ln for op in (
+                       "all-reduce", "all-reduce-start", "all-gather",
+                       "all-to-all", "collective-permute",
+                       "reduce-scatter"))]
+    assert len(collectives) == 1, collectives
+    assert f"f32[{d}]" in collectives[0]
+    assert "cocoa/exchange/all_reduce" in collectives[0]
