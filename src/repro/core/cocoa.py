@@ -603,6 +603,43 @@ def _ell_attrs(cfg: CoCoAConfig, X) -> dict:
     return {"ell_width": width, "ell_pad": width - r_max}
 
 
+def _certificate_rows(X, y, mask):
+    """The certificate's layout of `SparseShards` X (or of `FeatureShards`
+    of one model shard, which hold the same rows), and y and mask in its
+    row order."""
+    if isinstance(X, FeatureShards):
+        X = SparseShards(X.cols[:, 0], X.vals[:, 0], X.nnz[:, 0], d=X.d)
+    layout = sparse_data.cert_layout(X)
+    return (layout,) + duality.in_rows(layout, y, mask)
+
+
+_layout_rows = jax.jit(_scoped("cocoa/place/certificate_layout",
+                              _certificate_rows))
+
+
+def _free_bytes(device) -> Optional[int]:
+    """Device memory not in use, or None where the device does not say
+    (the CPU)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def _layout_fits(X, y, mask) -> bool:
+    """Whether twice the certificate's layout of placed X -- the layout
+    and its build's transient copy of the rows -- fits in the memory each
+    of X's devices has free. Where it does not, the certificate keeps the
+    raw ELL passes, so the layout costs no capacity."""
+    nbytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        _layout_rows.eval_shape(X, y, mask)))
+    sharding = X.cols.sharding
+    share = (np.prod(sharding.shard_shape(X.cols.shape))
+             / np.prod(X.cols.shape))
+    free = [_free_bytes(dev) for dev in sharding.device_set]
+    return all(f is None or 2 * nbytes * share <= f for f in free)
+
+
 def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
           seed: int = 0, gap_every: int = 1, mesh=None, budget_fn=None,
           on_round: Optional[Callable[[int, CoCoAState, float], None]] = None,
@@ -626,13 +663,25 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     fed each round with (steps_done, fenced round seconds) -- its EMA
     rates and the budgets land in the records.
 
+    On `SparseShards` (and `FeatureShards` of one model shard) the
+    certificate reads its own copy of the rows, sorted by length in fixed
+    tiles (`data.sparse.CertLayout`), built once per solve where twice
+    its bytes fit the devices' free memory; the rounds and the returned
+    state keep the data's own order.
+
     The call runs under host spans (`obs.metrics.span`), which land in
     any active `jax.profiler` trace: `cocoa_solve` around it all (on ELL
     data that `sdca_sparse` solves, with attributes `ell_width` and
-    `ell_pad`: the width it runs at and the slots added to reach it), and
-    inside it `cocoa_lower` / `cocoa_compile` (tagged `what=round` or
-    `what=certificate`), `cocoa_place` (the state's host copy and the
-    data's placement), `cocoa_round` per round, `cocoa_certificate`,
+    `ell_pad`: the width it runs at and the slots added to reach it;
+    where the certificate's layout is built, `cert_slots` and
+    `ell_slots`: the slots it walks a call and the shards' own), and
+    inside it
+    `cocoa_lower` / `cocoa_compile` (tagged `what=round` or
+    `what=certificate`), `cocoa_place` (the state's host copy, the
+    data's placement and, tagged `what=certificate_layout`, the build of
+    the certificate's layout, with its compile on a process's first
+    solve of a shape), `cocoa_round` per round,
+    `cocoa_certificate`,
     `cocoa_record` (building and emitting a record) and `cocoa_on_round`
     around the caller's hook. A record's `host_s` is the call's time in
     none of the lowering, compile, round, certificate or hook spans.
@@ -642,7 +691,7 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
     along its feature axis to match (padded coordinates carry no data and
     stay exactly zero).
     """
-    with span("cocoa_solve", **_ell_attrs(cfg, X)):
+    with span("cocoa_solve", **_ell_attrs(cfg, X)) as solve_span:
         # host_s bookkeeping: the clock since the last record (since entry,
         # for the first), less what the lowering, compile, round,
         # certificate and hook spans below held
@@ -763,6 +812,19 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
         with span("cocoa_place", what="data"):
             X, y, mask, n = jax.device_put((X, y, mask, n),
                                            run_fn.input_shardings[0][1:5])
+        # the certificate's operands: on ELL shards with the global column
+        # ids, where it fits, their rows sorted by length (each chip sorts
+        # its own), built and fenced once here
+        cert_X, cert_y, cert_mask = X, y, mask
+        if ((isinstance(X, SparseShards) or (isinstance(X, FeatureShards)
+                                             and X.M == 1))
+                and _layout_fits(X, y, mask)):
+            with span("cocoa_place", what="certificate_layout"):
+                cert_X, cert_y, cert_mask = jax.block_until_ready(
+                    _layout_rows(X, y, mask))
+            solve_span.annotate(
+                cert_slots=int(cert_X.n_tiles) * cert_X.tile_slots,
+                ell_slots=int(np.prod(X.cols.shape)))
         gap_run = None
         base_round = int(state.rounds)
         gap = float("inf")
@@ -799,8 +861,9 @@ def solve(cfg: CoCoAConfig, X, y, mask, *, rounds: int, eps_gap: float = 0.0,
                     # a true bound by weak duality, and the projection
                     # residual vanishes as the iterates converge
                     alpha_eval = loss.project(alpha_eval, y)
-                gargs = ((state.w, alpha_eval, X, y, mask) if drifted
-                         else (alpha_eval, X, y, mask))
+                gargs = ((state.w, alpha_eval, cert_X, cert_y, cert_mask)
+                         if drifted
+                         else (alpha_eval, cert_X, cert_y, cert_mask))
                 if gap_run is None:
                     gap_run, lower_s, load_s = aot_stages(
                         gap_fn, *gargs, what="certificate")

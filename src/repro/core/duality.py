@@ -9,7 +9,11 @@ row i = x_i^T. Labels y and duals alpha are (K, n_k). A `mask` (K, n_k) of
 `X` may equivalently be a `repro.data.sparse.SparseShards` padded-ELL
 container; every objective then evaluates via the sparse matvec family
 (gather for A^T w, segment-sum scatter for A alpha) so gap certificates on
-sparse runs cost O(nnz), not O(n d).
+sparse runs cost O(nnz), not O(n d). A `repro.data.sparse.CertLayout` of
+those shards walks only the tiles of its sorted rows that hold entries.
+It is taken by `primal` and the gap functions alone: y and mask then
+come in its row order (`in_rows`, once per data set), alpha in the
+shards' own, and the gap functions put alpha in the layout's themselves.
 
 Objectives (regularizers.Regularizer, default the paper's L2):
 
@@ -31,14 +35,32 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import sparse as sparse_data
-from repro.data.sparse import FeatureShards, SparseShards
+from repro.data.sparse import CertLayout, FeatureShards, SparseShards
 
 from .losses import Loss
 from .regularizers import L2, Regularizer
 
 
+_SPARSE = (SparseShards, FeatureShards, CertLayout)
+
+
 def effective_n(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask)
+
+
+def in_rows(X, *xs: jnp.ndarray) -> tuple:
+    """The (K, nk) arrays `xs` in X's row order: a `CertLayout` sorts each
+    worker's rows; any other X keeps them as they are."""
+    if isinstance(X, CertLayout):
+        return tuple(X.rows(x) for x in xs)
+    return xs
+
+
+def _own_rows(X) -> None:
+    """Refuse a `CertLayout` where alpha is read in the shards' order."""
+    if isinstance(X, CertLayout):
+        raise TypeError("a CertLayout is read by `primal` and the gap "
+                        "functions alone")
 
 
 def _Atw(X, w: jnp.ndarray) -> jnp.ndarray:
@@ -46,7 +68,7 @@ def _Atw(X, w: jnp.ndarray) -> jnp.ndarray:
     padded (M*d_local,) w evaluate as per-shard local gathers summed over
     the model axis -- the one model-axis reduction a sharded certificate
     needs (sparse_data.matvec dispatches)."""
-    if isinstance(X, (SparseShards, FeatureShards)):
+    if isinstance(X, _SPARSE):
         return sparse_data.matvec(X, w)
     return jnp.einsum("kid,d->ki", X, w)
 
@@ -57,8 +79,14 @@ def v_of_alpha(X, alpha: jnp.ndarray, lam: float, n,
     rounds carry as shared state. X: (K, nk, d) or shards (FeatureShards
     yield the padded M*d_local global vector). Equals the paper's
     w(alpha) (eq. 3) under L2, where tau = lambda."""
+    _own_rows(X)
+    return _v(X, alpha, lam, n, reg)
+
+
+def _v(X, alpha, lam, n, reg):
+    """`v_of_alpha` on any X; on a `CertLayout`, alpha in its rows."""
     tau = reg.tau(lam)
-    if isinstance(X, (SparseShards, FeatureShards)):
+    if isinstance(X, _SPARSE):
         return sparse_data.rmatvec(X, alpha) / (tau * n)
     return jnp.einsum("kid,ki->d", X, alpha) / (tau * n)
 
@@ -111,7 +139,8 @@ def gap_decomposed(alpha, X, y, mask, loss, lam, reg: Regularizer = L2):
     each apart."""
     n = effective_n(mask)
     with jax.named_scope("rmatvec"):
-        v = v_of_alpha(X, alpha, lam, n, reg)
+        alpha, = in_rows(X, alpha)
+        v = _v(X, alpha, lam, n, reg)
     with jax.named_scope("primal"):
         w = reg.conj_grad(v, lam)
         p = primal(w, X, y, mask, loss, lam, reg)
@@ -138,7 +167,8 @@ def gap_at_w(w, alpha, X, y, mask, loss, lam, reg: Regularizer = L2):
     with jax.named_scope("primal"):
         p = primal(w, X, y, mask, loss, lam, reg)
     with jax.named_scope("rmatvec"):
-        v = v_of_alpha(X, alpha, lam, effective_n(mask), reg)
+        alpha, = in_rows(X, alpha)
+        v = _v(X, alpha, lam, effective_n(mask), reg)
     with jax.named_scope("dual"):
         d = dual_at_v(v, alpha, y, mask, loss, lam, reg)
     return p, d, p - d
@@ -153,5 +183,6 @@ def gap_at_v(v, alpha, X, y, mask, loss, lam, reg: Regularizer = L2):
 
 def u_vector(w: jnp.ndarray, X, y: jnp.ndarray, loss: Loss) -> jnp.ndarray:
     """u with -u_i in d l_i(x_i^T w)  (eq. 17) -- used in Lemma-5 style tests."""
+    _own_rows(X)
     z = _Atw(X, w)
     return loss.u_subgrad(z, y)

@@ -18,6 +18,9 @@ than necessary. This module provides the sparse pipeline end to end:
     K axis yields per-worker shards).
   * `partition_sparse` -- worker partitioner with the same shuffle, padding
     and mask semantics as `data.synthetic.partition` (shared `split_order`).
+  * `CertLayout` / `cert_layout` -- the certificate's copy of each
+    worker's rows, sorted by length and cut into fixed tiles, so its two
+    passes walk only the tiles that hold an entry.
   * `matvec` / `rmatvec` / `row_sqnorms` / `densify` -- the sparse matvec
     family used by `core.duality` for gap certificates and by tests.
 """
@@ -427,12 +430,123 @@ def shard_features_streaming(chunks, K: int, M: int = 1, *,
     return fs, jnp.asarray(yp), jnp.asarray(mask)
 
 
+# ----------------------------------------------------------------------------
+# The certificate's layout: rows sorted by length, walked in fixed tiles
+# ----------------------------------------------------------------------------
+
+CERT_BLOCK_ROWS = 2048   # rows of a tile
+CERT_CHUNK = 8           # ELL slots of a tile
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("order", "cols", "vals", "tiles", "n_tiles"),
+                   meta_fields=("d", "nk"))
+@dataclasses.dataclass(frozen=True)
+class CertLayout:
+    """A copy of `SparseShards` rows for the certificate's two passes.
+
+    Each worker's rows are sorted by the chunks of c slots they fill,
+    longest first, padded with empty rows to `nb` blocks of B rows, and
+    their slots padded to `nch` chunks; tile (b, j) holds chunk j of block b's rows, stored
+    slot-major (c, B) so the rows lie along the lanes. `tiles[:n_tiles]`
+    lists the (block, chunk) pairs whose chunk some worker's row reaches:
+    the passes walk those and skip the rest, which hold only padding.
+
+    Every shape is a function of (K, nk, r_max) alone; the data sets
+    only `tiles` and `n_tiles`, as values, so one compiled certificate
+    serves every data set of a shape. The tile list is shared by the
+    workers, so a walk sharded by workers needs no collective.
+
+    Row order: `matvec` returns z and `rmatvec` takes its coefficients in
+    the layout's order; `rows(x)` puts a (K, nk) array in it (the padding
+    rows read 0)."""
+    order: jnp.ndarray    # (K, nb*B) int32 source row; nk on padding rows
+    cols: jnp.ndarray     # (K, nb, nch, c, B) int32, padding -> 0
+    vals: jnp.ndarray     # (K, nb, nch, c, B) float32, padding -> 0.0
+    tiles: jnp.ndarray    # (nb*nch, 2) int32 (block, chunk), walked first
+    n_tiles: jnp.ndarray  # () int32 tiles that hold an entry
+    d: int
+    nk: int
+
+    def rows(self, x: jnp.ndarray) -> jnp.ndarray:
+        """(K, nk) in the source order -> (K, nb*B) in the layout's."""
+        return _in_order(x, self.order)
+
+    @property
+    def tile_slots(self) -> int:
+        """Slots of one tile over all workers: K * B * c."""
+        K, _, _, c, B = self.cols.shape
+        return K * B * c
+
+
+def _in_order(x: jnp.ndarray, order: jnp.ndarray) -> jnp.ndarray:
+    return jnp.take_along_axis(x, order, axis=-1, mode="fill", fill_value=0)
+
+
+def cert_layout(sh: SparseShards,
+                block_rows: int = CERT_BLOCK_ROWS) -> CertLayout:
+    """Build the certificate's layout of worker shards (K, nk, r_max) on
+    the device: a counting sort of each worker's rows by the chunks they
+    fill, longest first and stable (a comparison sort of nk keys compiles
+    for a TPU in about 16 s, the counting sort in about 2 s), and one
+    gather of whole rows. Jit-able; under a mesh each worker's rows stay
+    on its chip, and only the per-block chunk counts are reduced over
+    workers."""
+    K, nk, r_max = sh.cols.shape
+    chunk = CERT_CHUNK
+    B = min(block_rows, -(-nk // 128) * 128)
+    nb, nch = -(-nk // B), -(-r_max // chunk)
+    fills = -(-sh.nnz // chunk)                                  # (K, nk)
+    hot = (fills[..., None] == jnp.arange(nch, -1, -1)).astype(jnp.int32)
+    count = jnp.sum(hot, axis=1)                                 # (K, nch+1)
+    first = jnp.cumsum(count, axis=-1) - count
+    pos = jnp.sum((jnp.cumsum(hot, axis=1) - hot + first[:, None]) * hot,
+                  axis=-1)
+    order = jax.vmap(lambda p: jnp.full(nb * B, nk, jnp.int32).at[p].set(
+        jnp.arange(nk, dtype=jnp.int32), unique_indices=True))(pos)
+
+    def tiled(a):
+        # whole rows in the sorted order, then (nb, B, nch, c) -> slot-major
+        a = jax.vmap(lambda ak, ok: ak.at[ok].get(mode="fill", fill_value=0)
+                     )(a, order)
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, nch * chunk - r_max)))
+        return a.reshape(K, nb, B, nch, chunk).transpose(0, 1, 3, 4, 2)
+
+    # a block's first row is its longest on every worker
+    need = jnp.max(_in_order(fills, order)[:, ::B], axis=0)      # (nb,)
+    live = (jnp.arange(nch)[None, :] < need[:, None]).reshape(-1)
+    flat, = jnp.nonzero(live, size=nb * nch, fill_value=0)
+    tiles = jnp.stack([flat // nch, flat % nch], axis=-1).astype(jnp.int32)
+    return CertLayout(order, tiled(sh.cols), tiled(sh.vals), tiles,
+                      jnp.sum(live).astype(jnp.int32), d=sh.d, nk=nk)
+
+
+def _walk(lay: CertLayout, tile_fn, init, per_worker=None):
+    """Fold `tile_fn(block, cols (c, B), vals (c, B), x, acc)` over the
+    layout's live tiles, per worker (vmapped: a (K, c, B) tile a step);
+    `x` is the worker's slice of `per_worker`, or None."""
+    def worker(cols, vals, x, acc):
+        def step(t, acc):
+            b, j = lay.tiles[t, 0], lay.tiles[t, 1]
+            return tile_fn(b, cols[b, j], vals[b, j], x, acc)
+        return jax.lax.fori_loop(0, lay.n_tiles, step, acc)
+    return jax.vmap(worker, in_axes=(0, 0, None if per_worker is None
+                                     else 0, 0))(lay.cols, lay.vals,
+                                                 per_worker, init)
+
+
 def matvec(sh, w: jnp.ndarray) -> jnp.ndarray:
     """z = A^T w per row:  z_i = sum_r vals[i, r] * w[cols[i, r]].
 
     `FeatureShards` + padded (M*d_local,) w: per-shard local gathers
     summed over the model axis -- the one model-axis reduction a sharded
-    prediction needs."""
+    prediction needs. `CertLayout`: z over the live tiles, (K, nb*B) in
+    the layout's row order."""
+    if isinstance(sh, CertLayout):
+        K, nb, _, _, B = sh.cols.shape
+        z = _walk(sh, lambda b, c, v, _, z: z.at[b].add(
+            jnp.sum(v * w[c], axis=0)), jnp.zeros((K, nb, B), w.dtype))
+        return z.reshape(K, nb * B)
     if isinstance(sh, FeatureShards):
         w2 = w.reshape(sh.M, sh.d_local)
         per_m = jax.vmap(lambda wm, cm, vm: jnp.sum(vm * wm[cm], axis=-1),
@@ -445,7 +559,15 @@ def rmatvec(sh, coef: jnp.ndarray) -> jnp.ndarray:
     """A coef = sum_i coef_i x_i as a scatter-add (segment sum). Dense
     output is (d,) for `SparseShards`, the padded (M*d_local,) global
     vector for `FeatureShards` (per-shard local scatters, concatenated --
-    padded coordinates receive nothing)."""
+    padded coordinates receive nothing). `CertLayout` takes `coef` in its
+    row order, (K, nb*B), scatters each worker's live tiles into its own
+    (d,) and sums the workers once at the end."""
+    if isinstance(sh, CertLayout):
+        K, nb, _, _, B = sh.cols.shape
+        part = _walk(sh, lambda b, c, v, a, acc: acc.at[c].add(
+            v * a[b][None, :]), jnp.zeros((K, sh.d), sh.vals.dtype),
+            coef.reshape(K, nb, B))
+        return jnp.sum(part, axis=0)
     if isinstance(sh, FeatureShards) and sh.M == 1:
         # one slice holds the global ids: take the replicated layout's
         # scatter, so an M=1 certificate partitions (and rounds) the same

@@ -120,6 +120,10 @@ class span:
         self._annotation.__exit__(*exc)
         return False
 
+    def annotate(self, **attrs) -> None:
+        """Add attributes known only once the span has started."""
+        self._annotation.set_metadata(**attrs)
+
 
 def fenced_call(fn, *args, **kwargs):
     """Run `fn(*args)` and return `(out, seconds)` with the clock read

@@ -21,10 +21,11 @@ import numpy as np
 import pytest
 
 from repro.core import CoCoAConfig, duality, solve
-from repro.core.cocoa import _ell_attrs, _scoped
+from repro.core.cocoa import _ell_attrs, _layout_rows, _scoped
 from repro.core.solvers import ell_width
 from repro.core.losses import get_loss
 from repro.data import load, partition, partition_sparse
+from repro.data import sparse as sparse_data
 from repro.obs import (Aggregator, EventBus, aot_compile, aot_stages, span,
                        validate_record)
 
@@ -157,3 +158,51 @@ def test_ell_attrs_only_where_the_jnp_solver_runs():
     # the Pallas kernel walks the shard's own slots
     assert _ell_attrs(CoCoAConfig.adding(K, solver="sdca_kernel"), X) == {}
     assert _ell_attrs(CoCoAConfig.adding(K), _data("dense")[0]) == {}
+
+
+def _traced_solve_spans(kind, tmp_path):
+    """The `cocoa_solve` and `cocoa_place` events of one traced vmap
+    solve: [(name, stats)]."""
+    X, y, mask = _data(kind)
+    cfg = CoCoAConfig.adding(K, loss="hinge", lam=1e-3, H=32)
+    with jax.profiler.trace(str(tmp_path)):
+        solve(cfg, X, y, mask, rounds=1, seed=0)
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_serialized_xspace(
+        pathlib.Path(path).read_bytes())
+    return [(ev.name, dict(ev.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events
+            if ev.name in ("cocoa_solve", "cocoa_place")]
+
+
+@pytest.mark.parametrize("kind", ["dense", "ell"])
+def test_solve_span_carries_the_certificate_slots(kind, tmp_path):
+    """On ELL data `cocoa_solve` carries the slots the certificate walks
+    a call and the shards' own, and the layout's build runs under its own
+    `cocoa_place` span; on dense data there is neither."""
+    events = _traced_solve_spans(kind, tmp_path)
+    stats, = [s for n, s in events if n == "cocoa_solve"]
+    builds = [s for n, s in events
+              if n == "cocoa_place" and s.get("what") == "certificate_layout"]
+    if kind == "dense":
+        assert not {"cert_slots", "ell_slots"} & set(stats)
+        assert not builds
+        return
+    X, _, _ = _data(kind)
+    lay = jax.jit(sparse_data.cert_layout)(X)
+    assert len(builds) == 1
+    assert stats["ell_slots"] == int(np.prod(X.cols.shape))
+    assert stats["cert_slots"] == int(lay.n_tiles) * lay.tile_slots
+
+
+def test_certificate_layout_ops_are_not_the_certificate():
+    """The layout's build labels its ops `cocoa/place/certificate_layout`,
+    which the benchmark's substring match on `cocoa/certificate` does not
+    count as the certificate."""
+    X, y, mask = _data("ell")
+    build = _layout_rows.lower(*jax.device_put((X, y, mask))).compile()
+    op_names = set(re.findall(r'op_name="([^"]*)"', build.as_text()))
+    assert any("cocoa/place/certificate_layout" in op for op in op_names)
+    assert not any("cocoa/certificate" in op for op in op_names)

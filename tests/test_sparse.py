@@ -2,6 +2,7 @@
 partitioner parity with the dense contract, sparse duality-gap evaluation,
 and the Pallas sparse LocalSDCA kernel vs its pure-jnp oracle (bit-for-bit,
 same visit order -- not statistical)."""
+import functools
 import os
 import subprocess
 import sys
@@ -256,6 +257,217 @@ def test_sparse_gap_matches_densified():
     assert abs(float(ps) - float(pd)) < 1e-5
     assert abs(float(ds) - float(dd)) < 1e-5
     assert abs(float(gs) - float(gd)) < 1e-5
+
+
+# ----------------------------------------------------------------------------
+# the certificate's layout: rows sorted by length, walked in live tiles
+# ----------------------------------------------------------------------------
+
+def _csr_of_lengths(lengths, d, seed=0):
+    """Rows with the given entry counts, distinct sorted columns."""
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths, np.int64)
+    cols = [np.sort(rng.choice(d, int(n), replace=False)) for n in lengths]
+    indptr = np.concatenate([[0], np.cumsum(lengths)])
+    data = (0.2 * rng.standard_normal(indptr[-1])).astype(np.float32)
+    csr = sp.CSRMatrix(data, np.concatenate(cols + [np.zeros(0)]).astype(
+        np.int32), indptr, (len(lengths), d))
+    y = rng.choice([-1.0, 1.0], len(lengths)).astype(np.float32)
+    return csr, y
+
+
+def _layout_case(case, K):
+    """(shards, y, mask) of one case; row counts leave padding rows on
+    eight workers and blocks of 16 rows that the rows do not fill."""
+    rng = np.random.default_rng(5)
+    if case == "tiny_sparse":
+        from repro.data import load
+        csr, y = load("tiny_sparse")
+        return sp.partition_sparse(csr, y, K, seed=0)
+    n, d, r_max = 203, 96, 40
+    lengths = {
+        "skewed": np.minimum(rng.geometric(0.08, n), r_max),
+        "empty_rows": np.where(rng.random(n) < 0.5, 0,
+                               rng.integers(1, r_max + 1, n)),
+        "full_width": np.full(n, r_max),
+    }[case]
+    lengths[0] = r_max        # every case reaches the full width once
+    csr, y = _csr_of_lengths(lengths, d)
+    return sp.partition_sparse(csr, y, K, seed=1, r_max=r_max)
+
+
+LAYOUT_CASES = ["tiny_sparse", "skewed", "empty_rows", "full_width"]
+
+
+def _undo(layout, z):
+    """z in the layout's row order -> the shards' (padding rows dropped)."""
+    order, z = np.asarray(layout.order), np.asarray(z)
+    out = np.zeros((order.shape[0], layout.nk), z.dtype)
+    for k in range(order.shape[0]):
+        real = order[k] < layout.nk
+        out[k, order[k, real]] = z[k, real]
+    return out
+
+
+@pytest.mark.parametrize("K", [1, 8])
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_cert_layout_passes_match_the_ell_passes(case, K):
+    """z = A^T w and v = A alpha over the live tiles equal the raw ELL
+    passes, once the rows are put back in their order; the rows the
+    layout adds read 0."""
+    sh, yp, mk = _layout_case(case, K)
+    lay = jax.jit(lambda s: sp.cert_layout(s, block_rows=16))(sh)
+    K_, nk, _ = sh.cols.shape
+    assert nk % 16 or case == "tiny_sparse"
+    rng = np.random.default_rng(3)
+    w = jnp.asarray(rng.standard_normal(sh.d).astype(np.float32))
+    alpha = jnp.asarray(rng.standard_normal(yp.shape).astype(np.float32))
+    z = jax.jit(sp.matvec)(lay, w)
+    np.testing.assert_allclose(_undo(lay, z), np.asarray(sp.matvec(sh, w)),
+                               rtol=1e-6, atol=1e-6)
+    assert not np.any(np.asarray(z)[np.asarray(lay.order) >= nk])
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(sp.rmatvec)(lay, lay.rows(alpha))),
+        np.asarray(sp.rmatvec(sh, alpha)), rtol=1e-6, atol=1e-6)
+    # each worker's rows longest first, and a permutation of its own
+    fills = -(-np.asarray(lay.rows(sh.nnz)) // sp.CERT_CHUNK)
+    assert np.all(np.diff(fills, axis=1) <= 0)
+    for k in range(K_):
+        real = np.asarray(lay.order[k])
+        assert sorted(real[real < nk]) == list(range(nk))
+
+
+@pytest.mark.parametrize("K", [1, 8])
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+@pytest.mark.parametrize("gap", ["gap_decomposed", "gap_at_v"])
+def test_cert_layout_certificate_matches_the_ell_certificate(gap, case, K):
+    """(P, D, gap) on the layout, with y and mask in its row order, equal
+    the certificate on the raw shards to 1e-6 of the certificate's scale,
+    max(|P|, |D|): D and the gap are differences of sums of that size,
+    and the layout adds in another order."""
+    sh, yp, mk = _layout_case(case, K)
+    lay = jax.jit(lambda s: sp.cert_layout(s, block_rows=16))(sh)
+    rng = np.random.default_rng(4)
+    alpha = jnp.asarray(rng.random(yp.shape).astype(np.float32)) * yp * mk
+    fn = functools.partial(getattr(duality, gap),
+                           loss=get_loss("smooth_hinge"), lam=1e-3)
+    lead = ()
+    if gap == "gap_at_v":
+        lead = (duality.v_of_alpha(sh, alpha, 1e-3, jnp.sum(mk))
+                + 1e-3 * jnp.asarray(rng.standard_normal(sh.d), jnp.float32),)
+    want = fn(*lead, alpha, sh, yp, mk)
+    got = jax.jit(fn)(*lead, alpha, lay, *duality.in_rows(lay, yp, mk))
+    scale = max(abs(float(want[0])), abs(float(want[1])))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-6 * scale)
+
+
+def test_cert_layout_walks_only_the_tiles_that_hold_entries():
+    """Rows of 1 to 16 slots in blocks of 16 rows and chunks of 8: the
+    layout walks one chunk where a block's rows all fit in 8 slots."""
+    lengths = np.array([16] * 3 + [8] * 29 + [0] * 32)
+    csr, y = _csr_of_lengths(lengths, 64)
+    sh, _, _ = sp.partition_sparse(csr, y, 1, seed=0, r_max=16)
+    lay = sp.cert_layout(sh, block_rows=16)
+    # four blocks of 16 rows: (3 x 16 + 13 x 8), 16 x 8, then empty rows
+    assert lay.cols.shape == (1, 4, 2, 8, 16)
+    assert int(lay.n_tiles) == 3
+    assert [tuple(t) for t in np.asarray(lay.tiles[:3])] == [
+        (0, 0), (0, 1), (1, 0)]
+    assert int(lay.n_tiles) * lay.tile_slots < lay.cols.size
+
+
+def test_cert_layout_lowers_the_same_for_every_nnz_histogram():
+    """Two data sets of one (K, nk, r_max, d) with different row lengths
+    lower the layout's build and the certificate to the same program
+    text: only the tile list and its count are data."""
+    n, d, r_max, K = 4200, 96, 40, 2      # two blocks of 2,048 rows
+    rng = np.random.default_rng(9)
+    short = np.minimum(rng.geometric(0.3, n), r_max)
+    long_ = np.maximum(r_max - rng.geometric(0.3, n), 1)
+    short[0] = long_[0] = r_max
+    from repro.core.cocoa import _certificate_rows
+    fn = functools.partial(duality.gap_decomposed,
+                           loss=get_loss("smooth_hinge"), lam=1e-3)
+    texts, tiles = [], []
+    for lengths in (short, long_):
+        csr, y = _csr_of_lengths(lengths, d)
+        sh, yp, mk = sp.partition_sparse(csr, y, K, seed=1, r_max=r_max)
+        build = jax.jit(_certificate_rows)
+        lay, ys, ms = build(sh, yp, mk)
+        alpha = jnp.zeros(yp.shape, jnp.float32)
+        texts.append((build.lower(sh, yp, mk).as_text(),
+                      jax.jit(fn).lower(alpha, lay, ys, ms).as_text()))
+        tiles.append(int(lay.n_tiles))
+    assert tiles[0] < tiles[1]
+    assert texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("accel", ["none", "nesterov"])
+def test_solve_certifies_the_raw_shards_gap(accel):
+    """`solve` on ELL shards certifies through the layout
+    (`gap_decomposed`, or `gap_at_v` under momentum): every record's
+    (P, D, gap) equals the certificate of the round's state on the raw
+    shards."""
+    _, _, (sh, yp, mk) = _problem(n=512, d=256, density=0.05, K=4, seed=23)
+    cfg = CoCoAConfig.adding(4, loss="smooth_hinge", lam=1e-3, H=64,
+                             accel=accel)
+    fn = (duality.gap_at_v if accel != "none" else
+          lambda v, *a, **kw: duality.gap_decomposed(*a, **kw))
+    want = []
+
+    def hook(t, state, gap):
+        want.append(fn(state.w, state.alpha, sh, yp, mk,
+                       get_loss("smooth_hinge"), 1e-3))
+    r = solve(cfg, sh, yp, mk, rounds=4, gap_every=1, seed=3, on_round=hook)
+    got = np.stack([r.history["primal"], r.history["dual"],
+                    r.history["gap"]], axis=1)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5)
+
+
+def test_only_primal_and_the_gap_functions_take_a_cert_layout():
+    """The functions that read alpha in the shards' own order refuse a
+    `CertLayout`, whose passes read it in the layout's."""
+    sh, yp, mk = _layout_case("skewed", 2)
+    lay = sp.cert_layout(sh, block_rows=16)
+    alpha = jnp.zeros(yp.shape, jnp.float32)
+    loss = get_loss("smooth_hinge")
+    for call in (lambda: duality.v_of_alpha(lay, alpha, 1e-3, 1.0),
+                 lambda: duality.w_of_alpha(lay, alpha, 1e-3, 1.0),
+                 lambda: duality.dual(alpha, lay, yp, mk, loss, 1e-3),
+                 lambda: duality.u_vector(jnp.zeros(sh.d), lay, yp, loss)):
+        with pytest.raises(TypeError, match="CertLayout"):
+            call()
+    assert duality.in_rows(sh, yp, mk) == (yp, mk)
+
+
+def test_solve_keeps_the_raw_passes_where_the_layout_does_not_fit(
+        monkeypatch):
+    """On a device with no memory free the certificate reads the raw
+    shards, and certifies the same gaps as through the layout."""
+    from repro.core import cocoa
+    _, _, (sh, yp, mk) = _problem(n=512, d=256, density=0.05, K=4, seed=23)
+    cfg = CoCoAConfig.adding(4, loss="smooth_hinge", lam=1e-3, H=64)
+    built = []
+
+    class Counted:
+        def __init__(self, build):
+            self.build, self.eval_shape = build, build.eval_shape
+
+        def __call__(self, *args):
+            built.append(1)
+            return self.build(*args)
+
+    monkeypatch.setattr(cocoa, "_layout_rows", Counted(cocoa._layout_rows))
+    want = solve(cfg, sh, yp, mk, rounds=3, gap_every=1, seed=3).history
+    assert built
+    built.clear()
+    monkeypatch.setattr(cocoa, "_free_bytes", lambda device: 0)
+    got = solve(cfg, sh, yp, mk, rounds=3, gap_every=1, seed=3).history
+    assert not built
+    np.testing.assert_allclose(
+        np.asarray([got["primal"], got["dual"], got["gap"]]),
+        np.asarray([want["primal"], want["dual"], want["gap"]]), rtol=1e-5)
 
 
 # ----------------------------------------------------------------------------

@@ -13,6 +13,8 @@ chips, one worker a chip. The topology is described inside a fixture,
 never at import time: only the worker that runs this file loads the TPU
 library.
 """
+import functools
+import re
 import time
 
 import jax
@@ -198,3 +200,104 @@ def test_mesh_round_all_reduces_once_under_its_scope(topo):
     assert len(collectives) == 1, collectives
     assert f"f32[{d}]" in collectives[0]
     assert "cocoa/exchange/all_reduce" in collectives[0]
+
+
+def _computations(hlo_text: str) -> dict:
+    """{computation name: its instruction lines} of compiled HLO text."""
+    out, name = {}, None
+    for ln in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", ln)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif ln == "}":
+            name = None
+        elif name is not None:
+            out[name].append(ln)
+    return out
+
+
+def _collectives(lines) -> list:
+    return [ln for ln in lines if " = " in ln and any(f" {op}(" in ln for op in (
+        "all-reduce", "all-reduce-start", "all-gather", "all-to-all",
+        "collective-permute", "reduce-scatter"))]
+
+
+def _certificate_layout_of(workers, nk, r_max, S):
+    """(certificate operands, the layout's compiled build) on ELL shards
+    (workers, nk, r_max) placed by `S`: alpha as the rounds keep it, and
+    the layout, y and mask where the build leaves them."""
+    from repro.core.cocoa import _certificate_rows
+    X = SparseShards(S((workers, nk, r_max), jnp.int32),
+                     S((workers, nk, r_max)), S((workers, nk), jnp.int32),
+                     d=RCV1["d"])
+    build = jax.jit(_certificate_rows).lower(
+        X, S((workers, nk)), S((workers, nk))).compile()
+    outs = jax.eval_shape(_certificate_rows, X, S((workers, nk)),
+                          S((workers, nk)))
+    outs = jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sh), outs, build.output_shardings)
+    return (S((workers, nk)),) + tuple(outs), build
+
+
+def _certificate_text(args) -> tuple:
+    """(compiled text, compile seconds) of `solve`'s certificate."""
+    from repro.core import duality
+    from repro.core.cocoa import _scoped
+    fn = jax.jit(_scoped("cocoa/certificate", functools.partial(
+        duality.gap_decomposed, loss=LOSS, lam=1e-4)))
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args).compile()
+    return compiled.as_text(), time.perf_counter() - t0
+
+
+def test_certificate_walks_tiles_at_rcv1_shape(spec):
+    """At the rcv1 cell's shape the certificate's gather and scatter-add
+    run on one (K, 8, 2,048) tile a step, never on the (K, nk, r_max)
+    slots: the primal gathers a f32[8, 8, 2048] tile, and the rmatvec
+    scatters its 131,072 updates into the workers' flat (K * d) vector."""
+    nk, r_max = 84_675, 122
+    args, _ = _certificate_layout_of(K, nk, r_max, spec)
+    assert args[1].cols.shape == (K, 42, 16, 8, 2048)
+    text, _ = _certificate_text(args)
+    tile = K * 8 * 2048
+    assert any(" gather(" in ln and f"f32[{K},8,2048]" in ln
+               and "cocoa/certificate/primal" in ln
+               for ln in text.splitlines())
+    assert any(any(f"s32[{tile}]" in ln for ln in lines)
+               for lines in _computations(text).values()
+               if any(" scatter(" in ln and f"f32[{K * RCV1['d']}]" in ln
+                      for ln in lines))
+    assert f"[{K},{nk},{r_max}]" not in text
+    assert f"[{K * nk * r_max}]" not in text
+
+
+def test_mesh_certificate_all_reduces_outside_its_walk(topo):
+    """The certificate on the rcv1 rows, one worker on each chip of the
+    v5e 2x2: its collectives are the parent's two all-reduces (v with n;
+    the primal and dual scalars), none inside a `while` loop, and it
+    compiles in seconds (the raw-ELL certificate took about 126 s). The
+    layout's build all-reduces once: the widest row of each block."""
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    mesh = Mesh(topo.devices, ("data",), axis_types=(AxisType.Auto,))
+    workers, nk, r_max = 4, 169_350, 122
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P("data")))
+    args, build = _certificate_layout_of(workers, nk, r_max, S)
+    text, seconds = _certificate_text(args)
+    assert seconds < 60, seconds
+    comps = _computations(text)
+    collectives = _collectives(ln for lines in comps.values()
+                               for ln in lines)
+    assert len(collectives) == 2, collectives
+    assert all(" all-reduce(" in ln for ln in collectives), collectives
+    assert any(f"f32[{RCV1['d']}]" in ln for ln in collectives)
+    bodies = {m for lines in comps.values() for ln in lines
+              for m in re.findall(r"body=%?([\w.\-]+)", ln)}
+    assert bodies
+    assert not _collectives(ln for b in bodies for ln in comps[b])
+    reduced, = _collectives(build.as_text().splitlines())
+    assert " all-reduce(" in reduced and "s32[83]" in reduced
