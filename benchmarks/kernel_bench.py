@@ -706,31 +706,8 @@ def run(quick: bool = True):
     print(f"kernel,pallas_interpret_roundtrip_s,{dt:.2f}")
     vm = vmem_analysis()
     print(f"kernel,vmem_total_mb,{vm['total_mb']:.2f},fits={vm['fits_16mb']}")
-    # fused selective-scan kernel: interpret-mode validation + HBM model
-    from repro.kernels.ssm_scan import ssm_scan_pallas, vmem_budget
-    from repro.kernels.ref import ssm_scan_ref
-    r = np.random.default_rng(0)
-    B, S, di, N = 1, 32, 256, 16
-    a = (r.standard_normal((B, S, di)).astype(np.float32),
-         np.abs(r.standard_normal((B, S, di))).astype(np.float32) * 0.1,
-         r.standard_normal((B, S, N)).astype(np.float32),
-         r.standard_normal((B, S, N)).astype(np.float32),
-         -np.abs(r.standard_normal((di, N))).astype(np.float32),
-         np.ones(di, np.float32))
-    y_k = ssm_scan_pallas(*map(jnp.asarray, a), block_d=128, interpret=True)
-    y_r = ssm_scan_ref(*map(jnp.asarray, a))
-    err = float(jnp.max(jnp.abs(y_k - y_r)))
-    svm = vmem_budget(block_d=256, S=512, N=16)
-    # HBM traffic: fused (streams only) vs jnp path (materializes (S,bd,N))
-    fused = (3 * di + 2 * N) * S * 4
-    jnp_path = fused + 3 * S * di * N * 4
-    print(f"kernel,ssm_scan_err,{err:.2e}")
-    print(f"kernel,ssm_scan_vmem_mb,{svm['total_mb']:.2f},fits={svm['fits_16mb']}")
-    print(f"kernel,ssm_scan_hbm_cut,{jnp_path/fused:.1f}x")
     sparse = sparse_roofline(quick=quick)
-    save("kernel_bench", dict(jnp_us_per_step=us, vmem=vm, ssm_err=err,
-                              ssm_vmem=svm, ssm_hbm_cut=jnp_path / fused,
-                              sparse=sparse))
+    save("kernel_bench", dict(jnp_us_per_step=us, vmem=vm, sparse=sparse))
     obs_quick(quick=quick)
     return vm
 

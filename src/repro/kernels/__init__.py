@@ -6,12 +6,8 @@ local_sdca.py      the paper's LocalSDCA inner loop (Algorithm 2): u/dalpha
 sparse_sdca.py     the same loop over padded-ELL rows (gather-dot +
                    scatter-axpy on u): O(nnz) HBM traffic instead of O(d),
                    validated bit-for-bit against its oracle.
-ssm_scan.py        fused mamba-1 selective scan (falcon-mamba memory fix).
-flash_attention.py causal GQA flash attention with online softmax.
 ref.py             pure-jnp oracles; every kernel is validated allclose in
                    interpret mode (tests/test_kernels.py, tests/test_sparse.py).
 """
-from .flash_attention import flash_attention
-from .ssm_scan import ssm_scan_pallas
 from .local_sdca import local_sdca_pallas
 from .sparse_sdca import sparse_local_sdca

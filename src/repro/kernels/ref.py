@@ -105,16 +105,3 @@ def sparse_local_sdca_ref(cols, vals, y, alpha, mask, w, scale, *,
     dalpha, u = jax.lax.fori_loop(0, n_passes * nk, body, (dalpha0, u0))
     return dalpha, u - u0
 
-
-def ssm_scan_ref(xin, dt, Bm, Cm, A, D):
-    """Oracle for kernels.ssm_scan: direct sequential recurrence in f64-ish
-    f32, same math as models/ssm.py's chunked associative scan."""
-    B, S, di = xin.shape
-    N = Bm.shape[-1]
-    h = jnp.zeros((B, di, N), jnp.float32)
-    ys = []
-    for t in range(S):
-        decay = jnp.exp(dt[:, t, :, None] * A[None])             # (B,di,N)
-        h = decay * h + (dt[:, t] * xin[:, t])[..., None] * Bm[:, t, None, :]
-        ys.append(jnp.einsum("bdn,bn->bd", h, Cm[:, t]) + D * xin[:, t])
-    return jnp.stack(ys, axis=1)

@@ -6,18 +6,16 @@ if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_DRYRUN_DEVICES"])
 
-"""Multi-pod dry run: .lower().compile() every (architecture x input-shape x
-mesh) cell on the production meshes, record memory/cost/collective stats.
+"""Multi-pod dry run of the paper's workload: .lower().compile() one CoCoA+
+round (`configs.paper_svm`) on the production meshes and record its
+memory, cost and collective stats.
 
-    PYTHONPATH=src python -m repro.launch.dryrun --all
-    PYTHONPATH=src python -m repro.launch.dryrun --arch gemma-7b \
-        --shape train_4k --mesh single
+    PYTHONPATH=src python -m repro.launch.dryrun                # both meshes
+    PYTHONPATH=src python -m repro.launch.dryrun --mesh single
 
-Outputs one JSON per cell under benchmarks/results/dryrun/. These artifacts
-are the roofline inputs (benchmarks/roofline.py -> EXPERIMENTS.md).
+Outputs one JSON per mesh under benchmarks/results/dryrun/.
 """
 import argparse
-import functools
 import json
 import pathlib
 import time
@@ -27,9 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch import mesh as meshlib
-from repro.launch import sharding as Sh
-from repro.launch import specs as Sp
-from repro.launch.hlo_analysis import collective_stats, full_stats, total_wire_bytes
+from repro.launch.hlo_analysis import full_stats
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / \
     "results" / "dryrun"
@@ -68,127 +64,6 @@ def _cost_dict(compiled) -> dict:
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     return cost
-
-
-def _abstract_bytes(tree) -> int:
-    import math
-    return sum((math.prod(l.shape) if l.shape else 1) * l.dtype.itemsize
-               for l in jax.tree.leaves(tree))
-
-
-def lower_cell(cell: Sp.Cell, mesh, mesh_name: str) -> dict:
-    from repro.launch.serve import prefill_step, serve_step
-    from repro.launch.train import train_step
-
-    cfg = cell.cfg
-    kind, args = Sp.cell_inputs(cell)
-    mode = ("train" if kind == "train"
-            else ("serve_long" if cell.kind == "decode_long" else "serve"))
-    pspecs = Sh.param_specs(args[0], cfg, mesh, mode)
-
-    # activation/logits constraints (prevent GSPMD from all-reducing the
-    # full-vocab logits over the data axis -- see EXPERIMENTS.md section Perf)
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.models import model as M
-    ax = Sh.axes_for(mesh, mode)
-    M.set_shardings(
-        act=NamedSharding(mesh, P(ax.dp, ax.seq, None)),
-        logits=NamedSharding(mesh, P(ax.dp, None, "model")),
-    )
-    # FSDP just-in-time weight gather: pays off when amortized over many
-    # tokens (train/prefill); decode keeps weights resident 2-D sharded and
-    # lets tiny per-token partial activations psum instead (iteration B2)
-    gather = kind in ("train", "prefill")
-    M.set_param_gather(Sh.use_specs_fn(cfg, mesh, mode)
-                       if gather and
-                       os.environ.get("REPRO_NO_FSDP_GATHER") != "1"
-                       else None)
-    # explicit shard_map expert parallelism for MoE layers
-    from repro.models import layers as Ly
-    if cfg.n_experts and ax.dp:
-        Ly.set_moe_ctx(mesh=mesh, dp=ax.dp, tp="model", fsdp=ax.fsdp,
-                       gather_weights=gather)
-    else:
-        Ly.set_moe_ctx()
-
-    if kind == "train":
-        ospecs = Sh.opt_specs(pspecs)
-        bspecs = Sh.batch_specs(args[2], cfg, mesh, mode)
-        in_sh = (Sh.named(mesh, pspecs), Sh.named(mesh, ospecs),
-                 Sh.named(mesh, bspecs))
-        out_sh = (in_sh[0], in_sh[1], None)
-        fn = functools.partial(train_step, cfg=cfg)
-        donate = (0, 1)
-    elif kind == "prefill":
-        bspecs = Sh.batch_specs(args[1], cfg, mesh, mode)
-        cspecs = Sh.cache_specs(args[2], cfg, mesh, mode)
-        in_sh = (Sh.named(mesh, pspecs), Sh.named(mesh, bspecs),
-                 Sh.named(mesh, cspecs))
-        out_sh = (None, in_sh[2])
-        fn = functools.partial(prefill_step, cfg=cfg)
-        donate = (2,)
-    else:
-        cspecs = Sh.cache_specs(args[1], cfg, mesh, mode)
-        in_sh = (Sh.named(mesh, pspecs), Sh.named(mesh, cspecs), None, None)
-        out_sh = (None, in_sh[1])
-        fn = functools.partial(serve_step, cfg=cfg)
-        donate = (1,)
-
-    jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
-                     donate_argnums=donate)
-    t0 = time.time()
-    lowered = jitted.lower(*args)
-    t1 = time.time()
-    compiled = lowered.compile()
-    t2 = time.time()
-
-    cost = _cost_dict(compiled)
-    hlo = compiled.as_text()
-    st = full_stats(hlo)
-    rec = {
-        "arch": cell.arch, "shape": cell.shape, "mesh": mesh_name,
-        "mesh_shape": dict(zip(mesh.axis_names,
-                               [int(s) for s in mesh.devices.shape])),
-        "kind": kind,
-        "lower_s": round(t1 - t0, 2), "compile_s": round(t2 - t1, 2),
-        # trip-count-aware per-device numbers (launch/hlo_analysis.py)
-        "flops_per_device": st["dot_flops"],
-        "hbm_bytes_per_device": st["hbm_bytes"],
-        "collectives": st["collectives"],
-        "collective_wire_bytes_per_device": st["collective_wire_bytes"],
-        # raw XLA numbers for reference (while bodies counted once!)
-        "xla_cost_flops": float(cost.get("flops", -1)),
-        "xla_cost_bytes": float(cost.get("bytes accessed", -1)),
-        "memory_analysis": _mem_dict(compiled),
-        "global_param_bytes": _abstract_bytes(args[0]),
-        "n_devices": mesh.size,
-    }
-    return rec
-
-
-def run_cell(cell: Sp.Cell, mesh_name: str, outdir: pathlib.Path) -> dict:
-    outdir.mkdir(parents=True, exist_ok=True)
-    out = outdir / f"{cell.arch}__{cell.shape}__{mesh_name}.json"
-    if cell.skip:
-        rec = {"arch": cell.arch, "shape": cell.shape, "mesh": mesh_name,
-               "skipped": cell.skip}
-        out.write_text(json.dumps(rec, indent=1))
-        print(f"[skip] {cell.arch} x {cell.shape} ({mesh_name}): {cell.skip}")
-        return rec
-    mesh = _mesh(mesh_name)
-    try:
-        rec = lower_cell(cell, mesh, mesh_name)
-        print(f"[ok]   {cell.arch} x {cell.shape} ({mesh_name}): "
-              f"compile={rec['compile_s']}s flops/dev={rec['flops_per_device']:.3e} "
-              f"hbm={rec['hbm_bytes_per_device']:.3e} "
-              f"wire={rec['collective_wire_bytes_per_device']:.3e}B", flush=True)
-    except Exception as e:
-        rec = {"arch": cell.arch, "shape": cell.shape, "mesh": mesh_name,
-               "error": f"{type(e).__name__}: {e}",
-               "traceback": traceback.format_exc()[-4000:]}
-        print(f"[FAIL] {cell.arch} x {cell.shape} ({mesh_name}): {e}")
-    out.write_text(json.dumps(rec, indent=1))
-    return rec
 
 
 def run_paper_cell(mesh_name: str, outdir: pathlib.Path) -> dict:
@@ -249,37 +124,21 @@ def run_paper_cell(mesh_name: str, outdir: pathlib.Path) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch")
-    ap.add_argument("--shape")
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--paper", action="store_true",
-                    help="also dry-run the CoCoA+ round cell")
     ap.add_argument("--out", default=str(RESULTS))
     args = ap.parse_args()
 
     outdir = pathlib.Path(args.out)
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
 
-    cells = []
-    if args.all:
-        cells = Sp.all_cells()
-    elif args.arch:
-        shapes = [args.shape] if args.shape else list(Sp.SHAPES)
-        cells = [Sp.cell_for(args.arch, s) for s in shapes]
-
     n_fail = 0
     for mesh_name in meshes:
-        for cell in cells:
-            rec = run_cell(cell, mesh_name, outdir)
-            n_fail += 1 if "error" in rec else 0
-        if args.paper or args.all:
-            try:
-                run_paper_cell(mesh_name, outdir)
-            except Exception as e:
-                n_fail += 1
-                print(f"[FAIL] paper-svm ({mesh_name}): {e}")
-                traceback.print_exc()
+        try:
+            run_paper_cell(mesh_name, outdir)
+        except Exception as e:
+            n_fail += 1
+            print(f"[FAIL] paper-svm ({mesh_name}): {e}")
+            traceback.print_exc()
     print(f"done; failures={n_fail}")
     raise SystemExit(1 if n_fail else 0)
 

@@ -389,10 +389,6 @@ class HloModule:
         return dict(stats)
 
 
-def collective_stats(hlo: str) -> Dict[str, Dict[str, float]]:
-    return HloModule(hlo).collective_stats()
-
-
 def total_wire_bytes(stats: Dict[str, Dict[str, float]]) -> float:
     return sum(s["wire_bytes"] for s in stats.values())
 

@@ -12,8 +12,8 @@ from jax.sharding import AxisType
 
 
 def _auto_mesh(shape, axes):
-    # the LM sharding rules constrain through with_sharding_constraint,
-    # which needs untyped (Auto) axes; jax.make_mesh defaults to Explicit
+    # the CoCoA+ shard_map rounds need untyped (Auto) axes;
+    # jax.make_mesh defaults to Explicit
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -38,8 +38,8 @@ def initialize_distributed(coordinator: str | None = None,
     + process topology explicitly (or set JAX_COORDINATOR_ADDRESS /
     JAX_NUM_PROCESSES / JAX_PROCESS_ID). Call BEFORE any other jax API, then
     build meshes with make_production_mesh() -- jax.devices() spans all hosts
-    afterwards and every launcher in this package works unchanged (specs are
-    global; jit handles cross-host data placement).
+    afterwards. Nothing in this package calls it yet: `dryrun` builds its
+    meshes with this module's helpers on the devices of one process.
 
     Returns (process_index, process_count)."""
     import os

@@ -6,9 +6,6 @@ lower bound and the duality-gap certificate stays correct -- the run degrades
 instead of corrupting. The shared w must then be re-derived as w(alpha)
 (eq. 3) to stay consistent with the surviving duals; the data shard itself is
 re-read from storage (here: regenerated/reloaded by the caller).
-
-For the LM trainer, failure handling is checkpoint/restart
-(checkpoint.CheckpointManager + launch/train.py `start_step`).
 """
 from __future__ import annotations
 

@@ -121,101 +121,3 @@ def test_interpret_false_is_not_overridden_off_tpu():
                           loss=get_loss("hinge"), block_rows=64,
                           interpret=False)
 
-
-# ----------------------------------------------------------------------------
-# fused selective-scan kernel (mamba) -- the memory-roofline fix for
-# falcon-mamba train cells (EXPERIMENTS.md section Roofline)
-# ----------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,S,di,N,bd", [
-    (2, 32, 256, 16, 128), (1, 64, 512, 8, 256), (2, 48, 128, 16, 128),
-    (1, 16, 384, 4, 128),
-])
-def test_ssm_scan_kernel_matches_oracle(B, S, di, N, bd):
-    from repro.kernels.ref import ssm_scan_ref
-    from repro.kernels.ssm_scan import ssm_scan_pallas
-
-    rng = np.random.default_rng(B * S + di)
-    xin = rng.standard_normal((B, S, di)).astype(np.float32)
-    dt = np.abs(rng.standard_normal((B, S, di))).astype(np.float32) * 0.1
-    Bm = rng.standard_normal((B, S, N)).astype(np.float32)
-    Cm = rng.standard_normal((B, S, N)).astype(np.float32)
-    A = -np.abs(rng.standard_normal((di, N))).astype(np.float32)
-    D = np.ones(di, np.float32)
-    args = tuple(map(jnp.asarray, (xin, dt, Bm, Cm, A, D)))
-    y_k = ssm_scan_pallas(*args, block_d=bd, interpret=True)
-    y_r = ssm_scan_ref(*args)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
-                               rtol=2e-4, atol=2e-5)
-
-
-def test_ssm_scan_kernel_matches_model_chunked_scan():
-    """Kernel == the model's chunked associative-scan path (same recurrence)."""
-    from repro.kernels.ssm_scan import ssm_scan_pallas
-    from repro.models.ssm import _scan_chunk
-
-    rng = np.random.default_rng(7)
-    B, S, di, N = 2, 64, 128, 16
-    xin = jnp.asarray(rng.standard_normal((B, S, di)).astype(np.float32))
-    dt = jnp.asarray(np.abs(rng.standard_normal((B, S, di))).astype(np.float32) * 0.1)
-    Bm = jnp.asarray(rng.standard_normal((B, S, N)).astype(np.float32))
-    Cm = jnp.asarray(rng.standard_normal((B, S, N)).astype(np.float32))
-    A = jnp.asarray(-np.abs(rng.standard_normal((di, N))).astype(np.float32))
-    D = jnp.ones(di, jnp.float32)
-    a = jnp.exp(dt[..., None] * A)
-    b = (dt * xin)[..., None] * Bm[:, :, None, :]
-    hs, _ = _scan_chunk(jnp.zeros((B, di, N)), a, b)
-    y_model = jnp.einsum("bsdn,bsn->bsd", hs, Cm) + xin * D
-    y_k = ssm_scan_pallas(xin, dt, Bm, Cm, A, D, block_d=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_model),
-                               rtol=2e-4, atol=2e-5)
-
-
-def test_ssm_scan_vmem_budget():
-    from repro.kernels.ssm_scan import vmem_budget
-    # production falcon-mamba shapes: block 256 of d_inner 8192, chunk 512
-    vm = vmem_budget(block_d=256, S=512, N=16)
-    assert vm["fits_16mb"]
-
-
-# ----------------------------------------------------------------------------
-# causal flash-attention kernel (prefill/train attention hot spot)
-# ----------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,S,H,KV,hd,cap", [
-    (2, 128, 4, 2, 64, None),
-    (1, 256, 8, 2, 32, None),
-    (2, 200, 4, 4, 64, 50.0),    # ragged tail + softcap (gemma2-style)
-    (1, 96, 6, 1, 128, None),    # MQA
-])
-def test_flash_attention_matches_reference(B, S, H, KV, hd, cap):
-    from repro.kernels.flash_attention import flash_attention
-    from repro.models.layers import chunked_attention
-
-    rng = np.random.default_rng(B * S + H)
-    q = jnp.asarray(rng.standard_normal((B, S, H, hd)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((B, S, KV, hd)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((B, S, KV, hd)).astype(np.float32))
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    ref = chunked_attention(q, k, v, pos, softcap=cap, q_chunk=64)
-    got = flash_attention(q, k, v, softcap=cap, q_block=64, k_block=64,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
-
-def test_flash_attention_bf16():
-    from repro.kernels.flash_attention import flash_attention
-    from repro.models.layers import chunked_attention
-
-    rng = np.random.default_rng(3)
-    B, S, H, KV, hd = 1, 128, 4, 2, 64
-    q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((B, S, KV, hd)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((B, S, KV, hd)), jnp.bfloat16)
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    ref = chunked_attention(q, k, v, pos, q_chunk=64)
-    got = flash_attention(q, k, v, q_block=64, k_block=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=5e-2, atol=5e-2)

@@ -1,35 +1,11 @@
-"""Optimizer substrate: AdamW, CoCoA-DP (localdp), compression."""
+"""Optimizer substrate: CoCoA-DP (localdp), compression."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.comm import compress as C
-from repro.optim import adamw_init, adamw_update
 from repro.optim.localdp import LocalDPConfig, init_state, make_round_fn
-
-
-def test_adamw_converges_quadratic():
-    target = jnp.asarray(np.random.default_rng(0).standard_normal(16),
-                         jnp.float32)
-    params = {"w": jnp.zeros(16, jnp.float32)}
-    opt = adamw_init(params)
-    loss = lambda p: jnp.sum((p["w"] - target) ** 2)
-    for _ in range(300):
-        g = jax.grad(loss)(params)
-        params, opt, _ = adamw_update(g, opt, params, lr=3e-2,
-                                      weight_decay=0.0)
-    assert float(loss(params)) < 1e-2
-
-
-def test_adamw_master_weights_dtype():
-    params = {"w": jnp.zeros(8, jnp.bfloat16)}
-    opt = adamw_init(params)
-    assert opt.master["w"].dtype == jnp.float32
-    g = {"w": jnp.ones(8, jnp.bfloat16)}
-    params, opt, gn = adamw_update(g, opt, params)
-    assert params["w"].dtype == jnp.bfloat16
-    assert float(gn) > 0
 
 
 def _mlp_problem(K=4, n_per=64, d=8, seed=0):

@@ -282,64 +282,14 @@ def test_localdp_shard_map_parity():
 
 
 @pytest.mark.slow
-def test_dryrun_cell_small_mesh():
-    """The dry-run driver end-to-end on a shrunken mesh (2x2 / 2x2x2)."""
+def test_dryrun_paper_cell_small_mesh(tmp_path):
     env = dict(os.environ)
     env["REPRO_DRYRUN_DEVICES"] = "8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     p = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", "--arch",
-         "stablelm-1.6b", "--shape", "train_4k", "--mesh", "both",
-         "--out", "/tmp/dryrun_test"],
-        capture_output=True, text=True, timeout=1200, env=env)
-    assert p.returncode == 0, p.stderr[-3000:]
-    assert p.stdout.count("[ok]") == 2
-
-
-@pytest.mark.slow
-def test_dryrun_paper_cell_small_mesh():
-    env = dict(os.environ)
-    env["REPRO_DRYRUN_DEVICES"] = "8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    p = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", "--paper", "--mesh",
-         "single", "--out", "/tmp/dryrun_test"],
+        [sys.executable, "-m", "repro.launch.dryrun", "--mesh", "single",
+         "--out", str(tmp_path)],
         capture_output=True, text=True, timeout=900, env=env)
     assert p.returncode == 0, p.stderr[-3000:]
     assert "paper-svm" in p.stdout
 
-
-def test_moe_shardmap_matches_portable():
-    """Explicit-EP MoE (shard_map) == portable grouped dispatch, both modes."""
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np, dataclasses
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.configs import smoke_config
-        from repro.models import layers as L
-
-        cfg = dataclasses.replace(smoke_config("llama4-scout-17b-a16e"),
-                                  capacity_factor=64.0)  # dropless -> exact
-        rng = np.random.default_rng(0)
-        B, S, d = 4, 16, cfg.d_model
-        x = jnp.asarray(rng.standard_normal((B, S, d)).astype(np.float32))
-        p = L.init_moe(jax.random.PRNGKey(1), cfg, cfg.d_ff, jnp.float32)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-
-        L.set_moe_ctx(groups=4)            # portable grouped path
-        ref, aux_ref = L.moe_forward(p, x, cfg, cfg.d_ff)
-
-        for gather in (True, False):
-            L.set_moe_ctx(mesh=mesh, dp="data", tp="model", fsdp="data",
-                          gather_weights=gather)
-            got, aux = jax.jit(lambda p, x: L.moe_forward(p, x, cfg, cfg.d_ff)
-                               )(p, x)
-            err = float(jnp.max(jnp.abs(got - ref)))
-            assert err < 2e-4, (gather, err)
-            # aux is E*sum(mean_e * count_e): the sharded path averages the
-            # per-shard statistic (GShard-style per-group balance), the
-            # portable path uses global means -- close but not identical
-            assert abs(float(aux) - float(aux_ref)) < 0.05
-        L.set_moe_ctx()                     # reset
-        print("MOE PARITY OK")
-    """)
-    assert "MOE PARITY OK" in out

@@ -12,6 +12,10 @@ The contract (core.solvers.register_solver):
     deadline accounting `runtime.straggler` budgets against),
   * dense/sparse twins (LocalSolver.sparse_name) agree on the same data.
 
+Each contract test runs every solver under every loss of `core.losses`;
+where a solver has no update for a loss (the Pallas kernels take only the
+closed-form ones) the case checks that it says so with a ValueError.
+
 Also pins the two `local_sdca_deadline` satellites of the accel PR: the
 hoisted-sqnorms path is bit-for-bit with the self-computed one, and a
 static (python int) budget -- which bounds the fori_loop trip count
@@ -26,12 +30,13 @@ import numpy as np
 import pytest
 
 from repro.core.cocoa import _worker_body
-from repro.core.losses import get_loss
+from repro.core.losses import LOSSES, get_loss
 from repro.core.regularizers import L2, get_regularizer
 from repro.core.solvers import (SOLVERS, LocalSolver, SDCAResult, get_solver,
                                 local_sdca_deadline, register_solver,
                                 sparse_counterpart)
 from repro.data.sparse import SparseShards, densify
+from repro.kernels.local_sdca import CLOSED_FORM_LOSSES
 
 NK, D = 64, 96
 MASKED = 9          # trailing padded rows
@@ -64,46 +69,75 @@ def _to_sparse(X):
                         d=d)
 
 
-def _run(solver: LocalSolver, *, budget=None, sqnorms=None, seed=0):
+KERNELS = ("sdca_kernel", "sdca_sparse_kernel")
+
+
+def _rejects(solver: LocalSolver, loss: str) -> bool:
+    """The Pallas kernels implement only the closed-form coordinate
+    updates; logistic's guarded Newton step stays on the jnp solvers."""
+    return solver.name in KERNELS and not loss.startswith(CLOSED_FORM_LOSSES)
+
+
+def _run(solver: LocalSolver, *, loss="smooth_hinge1", budget=None,
+         sqnorms=None, seed=0):
+    """One worker's local solve on the shared inputs. Where the solver
+    rejects `loss`, asserts the ValueError and returns None for the
+    result."""
     X, y, alpha, mask, v = _dense_inputs(seed)
     data = _to_sparse(X) if solver.sparse else X
     n = float(NK - MASKED)
-    res = _worker_body(data, y, alpha, mask, v, jax.random.PRNGKey(seed),
-                       loss=get_loss("smooth_hinge"), lam=LAM, n=n,
-                       sigma_p=SIGMA_P, H=H, solver=solver, budget=budget,
-                       sqnorms=sqnorms, reg=L2)
-    return res, (X, y, alpha, mask, v, n)
+    solve = functools.partial(
+        _worker_body, data, y, alpha, mask, v, jax.random.PRNGKey(seed),
+        loss=get_loss(loss), lam=LAM, n=n, sigma_p=SIGMA_P, H=H,
+        solver=solver, budget=budget, sqnorms=sqnorms, reg=L2)
+    if _rejects(solver, loss):
+        with pytest.raises(ValueError,
+                           match=f"kernel supports closed-form losses .*"
+                                 f"got {loss!r}"):
+            solve()
+        return None, (X, y, alpha, mask, v, n)
+    return solve(), (X, y, alpha, mask, v, n)
 
 
 ALL_SOLVERS = sorted(SOLVERS)
+ALL_LOSSES = sorted(LOSSES)
 
 
+@pytest.mark.parametrize("loss", ALL_LOSSES)
 @pytest.mark.parametrize("name", ALL_SOLVERS)
-def test_du_consistent_with_dalpha(name):
+def test_du_consistent_with_dalpha(name, loss):
     """du ~= (sigma'/(tau n)) A^T dalpha for every registered solver --
     the exchanged vector is exactly the image of the dual step taken."""
-    res, (X, y, alpha, mask, v, n) = _run(get_solver(name))
+    res, (X, y, alpha, mask, v, n) = _run(get_solver(name), loss=loss)
+    if res is None:
+        return
     scale = SIGMA_P / (L2.tau(LAM) * n)
     ref = scale * (np.asarray(X).T @ np.asarray(res.dalpha))
     np.testing.assert_allclose(np.asarray(res.du), ref, rtol=5e-4, atol=5e-5)
 
 
+@pytest.mark.parametrize("loss", ALL_LOSSES)
 @pytest.mark.parametrize("name", ALL_SOLVERS)
-def test_masked_rows_are_exact_noops(name):
+def test_masked_rows_are_exact_noops(name, loss):
     """Padding rows (mask 0) take no dual step at all."""
-    res, _ = _run(get_solver(name))
+    res, _ = _run(get_solver(name), loss=loss)
+    if res is None:
+        return
     tail = np.asarray(res.dalpha)[NK - MASKED:]
     assert float(np.max(np.abs(tail))) == 0.0, (name, tail)
 
 
+@pytest.mark.parametrize("loss", ALL_LOSSES)
 @pytest.mark.parametrize("name", ALL_SOLVERS)
-def test_steps_honestly_reported(name):
+def test_steps_honestly_reported(name, loss):
     """SDCAResult.steps reports the inner steps actually executed: H for
     the fixed-H solvers (the kernel rounds H onto whole passes of its
     padded block), min(H, budget) for the deadline solver."""
     ls = get_solver(name)
     assert ls.theta_steps, f"{name} must report Theta steps honestly"
-    res, _ = _run(ls, budget=(37 if ls.deadline else None))
+    res, _ = _run(ls, loss=loss, budget=(37 if ls.deadline else None))
+    if res is None:
+        return
     if ls.deadline:
         expected = min(H, 37)
     elif ls.name in ("sdca_kernel", "sdca_sparse_kernel"):
@@ -114,16 +148,21 @@ def test_steps_honestly_reported(name):
     assert int(res.steps) == expected, (ls.name, int(res.steps), expected)
 
 
+@pytest.mark.parametrize("loss", ALL_LOSSES)
 @pytest.mark.parametrize("name", [n for n in ALL_SOLVERS
                                   if get_solver(n).dense
                                   and sparse_counterpart(n) not in (None, n)])
-def test_dense_sparse_twins_agree(name):
+def test_dense_sparse_twins_agree(name, loss):
     """A solver and its declared padded-ELL twin take the same step on the
-    same data (the ELL form here stores every row exactly)."""
+    same data (the ELL form here stores every row exactly), and reject the
+    same losses."""
     dense = get_solver(name)
     twin = get_solver(sparse_counterpart(name))
-    r_dense, _ = _run(dense)
-    r_sparse, _ = _run(twin)
+    r_dense, _ = _run(dense, loss=loss)
+    r_sparse, _ = _run(twin, loss=loss)
+    assert (r_dense is None) == (r_sparse is None)
+    if r_dense is None:
+        return
     np.testing.assert_allclose(np.asarray(r_dense.du),
                                np.asarray(r_sparse.du),
                                rtol=5e-4, atol=5e-5)

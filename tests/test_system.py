@@ -1,9 +1,6 @@
-"""End-to-end behaviour: the paper's workload solved to certificate accuracy,
-and the LM trainer substrate actually learning."""
+"""End-to-end behaviour: the paper's workload solved to certificate accuracy."""
 import numpy as np
-import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.core import CoCoAConfig, solve
 from repro.data import load, partition
@@ -22,51 +19,3 @@ def test_end_to_end_svm_to_certificate():
     acc = float((np.sign(z) == np.asarray(yp))[np.asarray(mk) > 0].mean())
     assert acc > 0.8
 
-
-def test_lm_trainer_learns(rng):
-    """Tiny LM memorizes a repeating sequence (loss drops markedly)."""
-    from repro.configs import smoke_config
-    from repro.launch.train import train_step
-    from repro.models import model as M
-    from repro.optim.adamw import adamw_init
-    import functools
-
-    cfg = smoke_config("stablelm-1.6b")
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    opt = adamw_init(params)
-    toks = np.tile(np.arange(32) % 17 + 1, (4, 2)).astype(np.int32)
-    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
-    step = jax.jit(functools.partial(train_step, cfg=cfg, lr=3e-3))
-    l0 = None
-    for t in range(40):
-        params, opt, m = step(params, opt, batch)
-        if l0 is None:
-            l0 = float(m["loss"])
-    l1 = float(m["loss"])
-    assert np.isfinite(l1)
-    assert l1 < 0.5 * l0
-
-
-def test_serve_batched_requests(rng):
-    """Batched prefill+decode serving path produces tokens for every request."""
-    from repro.configs import smoke_config
-    from repro.launch.serve import prefill_step, serve_step
-    from repro.models import model as M
-    import functools
-
-    cfg = smoke_config("gemma-7b")
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    B, S = 4, 32
-    prompts = rng.integers(1, cfg.vocab, (B, 16)).astype(np.int32)
-    cache = M.init_cache(cfg, B, S)
-    logits, cache = jax.jit(functools.partial(prefill_step, cfg=cfg))(
-        params, {"tokens": prompts}, cache)
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-    dec = jax.jit(functools.partial(serve_step, cfg=cfg))
-    outs = []
-    for t in range(16, 24):
-        tok, cache = dec(params, cache, tok, t)
-        outs.append(np.asarray(tok))
-    outs = np.concatenate(outs, axis=1)
-    assert outs.shape == (B, 8)
-    assert (outs >= 0).all() and (outs < cfg.vocab).all()
